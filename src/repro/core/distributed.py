@@ -17,22 +17,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                  # jax >= 0.6: top-level, 'check_vma'
-    from jax import shard_map as _shard_map
-    _CHECK_KW = "check_vma"
-except ImportError:                   # jax 0.4/0.5: experimental, 'check_rep'
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` with replication checking disabled
-    (our out_specs mix replicated per-cluster state with sharded labels,
-    which the checker cannot verify across psum/all_gather)."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: False})
-
-
 def make_data_mesh(num_devices: Optional[int] = None) -> Mesh:
     """1-D mesh over all (or the first n) local devices, axis 'data'."""
     devs = jax.devices()

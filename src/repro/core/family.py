@@ -49,9 +49,13 @@ Registering a new family::
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
 import math
-from typing import Any, Callable, Dict, Optional, Tuple
+import threading
+import warnings
+from typing import Any, Callable, Dict, Iterator, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -71,6 +75,38 @@ from repro.kernels.sweep import STATS_BLOCK
 
 def _add_tree(a: Any, b: Any) -> Any:
     return jax.tree.map(jnp.add, a, b)
+
+
+# Which sweep body each traced ``ComponentFamily.sweep`` ran: "sweep_fast"
+# (the Pallas megakernel) or "sweep_ref" (the blocked jnp scan). The choice
+# is static, so it is made — and counted — when a program is traced; the
+# fit drivers open a recorder per fit and put the counts on FitResult.
+_RECORDERS = threading.local()
+
+
+class SweepFallbackWarning(UserWarning):
+    """``use_pallas=True`` traced a sweep that runs the jnp reference."""
+
+
+@contextlib.contextmanager
+def record_sweep_paths() -> Iterator[collections.Counter]:
+    """Count the sweep bodies traced in this thread while the block runs:
+    ``{"sweep_fast": n, "sweep_ref": m}`` (one per traced program branch,
+    not per iteration)."""
+    stack = getattr(_RECORDERS, "stack", None)
+    if stack is None:
+        stack = _RECORDERS.stack = []
+    counts: collections.Counter = collections.Counter()
+    stack.append(counts)
+    try:
+        yield counts
+    finally:
+        stack.remove(counts)
+
+
+def _note_sweep_path(path: str) -> None:
+    for counts in getattr(_RECORDERS, "stack", ()):
+        counts[path] += 1
 
 
 def fold_blocked(family: "ComponentFamily", k_max: int, body, x: jax.Array,
@@ -130,6 +166,56 @@ def fold_blocked(family: "ComponentFamily", k_max: int, body, x: jax.Array,
     else:
         labels = jnp.concatenate([o[0] for o in outs])
         sublabels = jnp.concatenate([o[1] for o in outs])
+    return labels, sublabels, acc
+
+
+def fold_chunked(run, x: jax.Array, valid: jax.Array, gidx: jax.Array, acc):
+    """Drive a megakernel ``run(x, valid, gidx) -> (labels, sublabels,
+    per-STATS_BLOCK partials)`` over STATS_BLOCK-aligned point chunks,
+    folding every partial into ``acc`` left to right in point order.
+
+    That is the add chain of one call over all of x, so the result is
+    bitwise the same; but the kernel's per-point-block stat partials,
+    which grow with the points of a call (4 GB of them for the Gaussian at
+    N=1e6, d=32, K=64), stay within ``ops.SWEEP_PARTIALS_BYTES``. Returns
+    None where the kernel refuses the shape.
+    """
+    from repro.kernels import ops
+    n = x.shape[0]
+    chunk = ops.sweep_chunk_points(
+        sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(acc)))
+
+    def fold(a, partials):
+        return jax.lax.scan(lambda a, p: (_add_tree(a, p), None), a,
+                            partials)[0]
+
+    if n <= chunk:
+        out = run(x, valid, gidx)
+        return None if out is None else (out[0], out[1], fold(acc, out[2]))
+    spec = lambda a: jax.ShapeDtypeStruct((chunk,) + a.shape[1:], a.dtype)
+    if jax.eval_shape(run, spec(x), spec(valid), spec(gidx)) is None:
+        return None
+    n_chunks, rem = divmod(n, chunk)
+
+    def body(c, carry):
+        labels, sublabels, a = carry
+        start = c * chunk
+        lab, sub, partials = run(*(
+            jax.lax.dynamic_slice_in_dim(v, start, chunk)
+            for v in (x, valid, gidx)))
+        put = lambda full, part: jax.lax.dynamic_update_slice_in_dim(
+            full, part, start, 0)
+        return put(labels, lab), put(sublabels, sub), fold(a, partials)
+
+    zeros = jnp.zeros((n,), jnp.int32)
+    labels, sublabels, acc = jax.lax.fori_loop(0, n_chunks, body,
+                                               (zeros, zeros, acc))
+    if rem:
+        tail = slice(n_chunks * chunk, None)
+        lab, sub, partials = run(x[tail], valid[tail], gidx[tail])
+        labels = labels.at[tail].set(lab)
+        sublabels = sublabels.at[tail].set(sub)
+        acc = fold(acc, partials)
     return labels, sublabels, acc
 
 
@@ -200,7 +286,9 @@ class ComponentFamily:
         Dispatch: the ``sweep_fast`` megakernel (Pallas, kernels/sweep.py)
         when available and inside its VMEM envelope, else ``sweep_ref``
         (one ``lax.scan`` over STATS_BLOCK blocks running assign /
-        sub_assign / stats_from_labels while the block is resident). Both
+        sub_assign / stats_from_labels while the block is resident). The
+        body taken is counted (``record_sweep_paths``); a ``use_pallas``
+        sweep that lands on ``sweep_ref`` also warns with the reason. Both
         paths fold stat partials per STATS_BLOCK left-to-right and draw
         noise from the counter-based PRNG, so they produce the same chain
         as the pre-fusion three-pass formulation, bit for bit.
@@ -216,15 +304,27 @@ class ComponentFamily:
         ``key_z``/``key_zb``: raw (2,) uint32 key words
         (``prng.key_words``).
         """
-        if use_pallas and feat_axis is None and self.sweep_fast is not None:
-            out = self.sweep_fast(x, valid, params, subparams, logw,
-                                  sublogw, active, gidx, key_z, key_zb,
-                                  k_max, slots=slots, k_block=k_block)
-            if out is not None:
-                labels, sublabels, partials = out
-                acc, _ = jax.lax.scan(
-                    lambda a, p: (_add_tree(a, p), None), acc, partials)
-                return labels, sublabels, acc
+        if use_pallas:
+            if feat_axis is not None:
+                why = "x is feature-sharded"
+            elif self.sweep_fast is None:
+                why = f"family {self.name!r} has no megakernel"
+            else:
+                out = fold_chunked(
+                    lambda xc, vc, gc: self.sweep_fast(
+                        xc, vc, params, subparams, logw, sublogw, active,
+                        gc, key_z, key_zb, k_max, slots=slots,
+                        k_block=k_block),
+                    x, valid, gidx, acc)
+                if out is not None:
+                    _note_sweep_path("sweep_fast")
+                    return out
+                why = "the shape is outside the kernel's VMEM envelope"
+            warnings.warn(
+                f"use_pallas=True sweep of {self.name!r} at x{tuple(x.shape)}"
+                f", K={k_max} runs the jnp reference sweep_ref: {why}",
+                SweepFallbackWarning, stacklevel=2)
+        _note_sweep_path("sweep_ref")
         return self.sweep_ref(x, valid, params, subparams, logw, sublogw,
                               active, gidx, key_z, key_zb, k_max, acc,
                               use_pallas=use_pallas, feat_axis=feat_axis,

@@ -70,10 +70,9 @@ from repro.configs.base import DPMMConfig
 from repro.core import checkpoint as _checkpoint
 from repro.core import gibbs, splitmerge
 from repro.core.distributed import (data_axes_of, make_data_mesh,
-                                    n_data_shards, shard_map, shard_points,
-                                    tile_plan)
+                                    n_data_shards, shard_points, tile_plan)
 from repro.core.family import (ComponentFamily, get_family,
-                               state_partition_specs)
+                               record_sweep_paths, state_partition_specs)
 from repro.core.metrics import ari, nmi
 from repro.core.resilience import (DivergenceError, RetryPolicy,
                                    model_health, read_block_checked)
@@ -387,6 +386,13 @@ class FitResult:
     # per-worker shard row ranges, and respawn/reassignment tallies.
     # None for single-process fits.
     dist: Optional[Dict[str, Any]] = None
+    # sweep bodies traced for this fit, by path: "sweep_fast" (the Pallas
+    # megakernel) / "sweep_ref" (the blocked jnp scan) — one count per
+    # compiled program branch, not per iteration (core/family.py). A
+    # use_pallas fit whose count shows "sweep_ref" ran the reference.
+    # Worker processes of a cfg.workers fit trace their own and are not
+    # counted here.
+    sweep_paths: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     def chain(self, c: int) -> "FitResult":
         """Single-chain view of chain ``c`` (bitwise — pure slicing)."""
@@ -402,7 +408,7 @@ class FitResult:
             iter_times_s=self.iter_times_s,
             device_bytes=self.device_bytes, n_chains=1,
             score=float(np.asarray(self.score)[c]),
-            recoveries=self.recoveries)
+            recoveries=self.recoveries, sweep_paths=self.sweep_paths)
 
     def select_best(self) -> "FitResult":
         """The chain with the highest final posterior ``score``
@@ -474,10 +480,17 @@ def _measured_peak(rss_baseline: Optional[int] = None
     the source is reported as ``process_peak_rss_stale`` — the number is a
     ceiling inherited from earlier work, not this leg's footprint.
     """
-    stats = jax.local_devices()[0].memory_stats() or {}
+    device = jax.local_devices()[0]
+    stats = device.memory_stats() or {}
     peak = stats.get("peak_bytes_in_use")
     if peak is not None:
         return int(peak), "device.memory_stats"
+    if device.platform == "tpu":
+        # host RSS says nothing about HBM; never report it as device memory
+        raise RuntimeError(
+            "device.memory_stats() gave no peak_bytes_in_use on a TPU "
+            f"({device.device_kind}); refusing to report host RSS as the "
+            "fit's device memory")
     rss = _rss_peak_bytes()
     if rss is None:                           # non-POSIX: no measurement
         return None, "unavailable"
@@ -574,16 +587,17 @@ class DPMM:
                     f"for n_chains={n_chains}, k_max={self.cfg.k_max} — "
                     "checkpoint/config/chain-count mismatch")
         if self.cfg.workers:
-            return self._fit_distributed(source, iters, verbose,
-                                         n_chains=n_chains, key=key,
-                                         init_state=init_state,
-                                         dist_hooks=dist_hooks)
-        if self.cfg.tile_size is None and source.resident() is not None:
-            return self._fit_resident(source, iters, verbose,
-                                      n_chains=n_chains, key=key,
-                                      init_state=init_state)
-        return self._fit_tiled(source, iters, verbose, n_chains=n_chains,
-                               key=key, init_state=init_state)
+            driver = functools.partial(self._fit_distributed,
+                                       dist_hooks=dist_hooks)
+        elif self.cfg.tile_size is None and source.resident() is not None:
+            driver = self._fit_resident
+        else:
+            driver = self._fit_tiled
+        with record_sweep_paths() as paths:
+            result = driver(source, iters, verbose, n_chains=n_chains,
+                            key=key, init_state=init_state)
+        result.sweep_paths = dict(paths)
+        return result
 
     def _fit_distributed(self, source: DataSource, iters: int,
                          verbose: bool, n_chains: int = 1,
@@ -664,9 +678,13 @@ class DPMM:
                     lambda k: _init_local(k, x, valid, **kwargs), keys)
             return _init_local(keys, x, valid, **kwargs)
 
-        init = jax.jit(shard_map(
+        # check_vma=False on every shard_map here: the out_specs mix
+        # replicated per-cluster state with sharded labels, which the
+        # checker cannot verify across psum/all_gather
+        init = jax.jit(jax.shard_map(
             init_body, mesh=mesh,
-            in_specs=(rep, x_in_spec, shard_spec), out_specs=state_specs))
+            in_specs=(rep, x_in_spec, shard_spec), out_specs=state_specs,
+            check_vma=False))
 
         def make_chunk(length: int, k_c: Optional[int]):
             """`length` iterations in one jitted call, history on device.
@@ -693,9 +711,10 @@ class DPMM:
                                     length=length)
             hist_specs = {k: rep for k in _HIST_KEYS}
             return jax.jit(
-                shard_map(run, mesh=mesh,
-                          in_specs=(*state_specs, x_in_spec),
-                          out_specs=(state_specs, hist_specs)),
+                jax.shard_map(run, mesh=mesh,
+                              in_specs=(*state_specs, x_in_spec),
+                              out_specs=(state_specs, hist_specs),
+                              check_vma=False),
                 donate_argnums=(0, 1))
 
         rss0 = _rss_peak_bytes()
@@ -703,7 +722,7 @@ class DPMM:
         # are fine — every sweep recomputes them from the model. Used on
         # resume (no point in the checkpoint) AND on divergence rollback
         # (the donated chunk consumed the diverged point's buffers).
-        mk_point = jax.jit(shard_map(
+        mk_point = jax.jit(jax.shard_map(
             lambda v: PointState(
                 labels=jnp.zeros(((n_chains,) if multi else ())
                                  + v.shape, jnp.int32),
@@ -711,7 +730,8 @@ class DPMM:
                                     + v.shape, jnp.int32),
                 valid=(jnp.broadcast_to(v, (n_chains,) + v.shape)
                        if multi else v)),
-            mesh=mesh, in_specs=(shard_spec,), out_specs=point_specs))
+            mesh=mesh, in_specs=(shard_spec,), out_specs=point_specs,
+            check_vma=False))
         if init_state is not None:
             model = jax.device_put(_copy_state(init_state),
                                    NamedSharding(mesh, P()))
@@ -822,6 +842,7 @@ class DPMM:
         labels = np.asarray(jax.device_get(point.labels))[..., :n]
         device_bytes = {
             "mode": "resident",
+            "mesh_devices": int(mesh.devices.size),
             "est_peak_bytes": (_tree_bytes(xs) + _tree_bytes(valid)
                                + 2 * _tree_bytes(point)
                                + 2 * _tree_bytes(model)),
@@ -1073,7 +1094,7 @@ class DPMM:
                 mn, v, x_t, l, s, off, a))(means0, v0, lab, sub, acc)
 
         lab_specs = (lab_spec, lab_spec)
-        smap = functools.partial(shard_map, mesh=mesh)
+        smap = functools.partial(jax.shard_map, mesh=mesh, check_vma=False)
         sweep_tile_fn = jax.jit(smap(
             _sweep_tile_c, in_specs=(model_specs, x_spec, *lab_specs, rep,
                                      acc_specs),
@@ -1283,6 +1304,7 @@ class DPMM:
                        for k, v in history.items()}
         device_bytes = {
             "mode": "tiled",
+            "mesh_devices": int(mesh.devices.size),
             "tile_size": tiles[0][1],
             "est_peak_bytes": int(est_peak),
             **_peak_fields(rss0),

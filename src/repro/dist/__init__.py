@@ -14,6 +14,8 @@ failover (row ranges are reassigned to survivors and respawns; the fold
 replay order never changes).
 """
 from repro.dist.proto import ProtocolError
-from repro.dist.coordinator import Coordinator, DistHooks, fit_distributed
+from repro.dist.coordinator import (ChipHeldError, Coordinator, DistHooks,
+                                   fit_distributed)
 
-__all__ = ["Coordinator", "DistHooks", "ProtocolError", "fit_distributed"]
+__all__ = ["ChipHeldError", "Coordinator", "DistHooks", "ProtocolError",
+           "fit_distributed"]

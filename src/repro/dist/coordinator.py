@@ -476,6 +476,10 @@ def _materialize(source) -> Tuple[str, Optional[str]]:
     return path, path
 
 
+class ChipHeldError(RuntimeError):
+    """``cfg.workers`` asked for worker processes on a TPU backend."""
+
+
 def fit_distributed(dpmm, source, iters: int, verbose: bool, *,
                     key=None, init_state=None,
                     hooks: Optional[DistHooks] = None):
@@ -484,11 +488,21 @@ def fit_distributed(dpmm, source, iters: int, verbose: bool, *,
     the bitwise and failure contracts."""
     import jax
     import jax.numpy as jnp
+
+    if jax.default_backend() == "tpu":
+        # a TPU belongs to one process: this coordinator already holds it,
+        # so every JAX-importing worker would fail or hang waiting for it
+        raise ChipHeldError(
+            "cfg.workers starts worker processes that each need the "
+            "accelerator, but this process already holds the TPU. To use "
+            "several chips, leave cfg.workers unset and pass an "
+            "in-process shard_map mesh: DPMM(cfg, "
+            "mesh=make_data_mesh(jax.device_count())).")
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core import checkpoint, gibbs, splitmerge
     from repro.core.distributed import (data_axes_of, make_data_mesh,
-                                        n_data_shards, shard_map)
+                                        n_data_shards)
     from repro.core.family import state_partition_specs
     from repro.core.sampler import (_Recovery, _copy_state, _init_model,
                                     _k_compact, _move_key, _peak_fields,
@@ -531,7 +545,7 @@ def fit_distributed(dpmm, source, iters: int, verbose: bool, *,
         f: NamedSharding(mesh, getattr(acc_specs, f))
         for f in acc_shape._fields})
     local = lambda acc: jax.tree.map(lambda v: v[0], acc)
-    smap = functools.partial(shard_map, mesh=mesh)
+    smap = functools.partial(jax.shard_map, mesh=mesh, check_vma=False)
     finalize_fn = jax.jit(smap(
         lambda acc: gibbs.finalize_substats(family, local(acc), axes,
                                             None),

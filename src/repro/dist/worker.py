@@ -79,7 +79,7 @@ class WorkerRuntime:
         from repro.configs import DPMMConfig
         from repro.core import gibbs, splitmerge
         from repro.core.distributed import (data_axes_of, make_data_mesh,
-                                            shard_map, tile_plan)
+                                            tile_plan)
         from repro.core.family import get_family, state_partition_specs
         from repro.core.resilience import RetryPolicy, read_block_checked
         from repro.core.sampler import _init_labels
@@ -209,7 +209,8 @@ class WorkerRuntime:
 
         lab_spec = P(axes)
         lab_specs = (lab_spec, lab_spec)
-        smap = functools.partial(shard_map, mesh=mesh)
+        smap = functools.partial(jax.shard_map, mesh=mesh,
+                                 check_vma=False)
         self.sweep_tile_fn = jax.jit(smap(
             _sweep_tile_c, in_specs=(model_specs, x_spec, *lab_specs, rep,
                                      acc_specs),

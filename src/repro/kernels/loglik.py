@@ -23,26 +23,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import ref
+from repro.kernels.assign import _cols, col_spec, gauss_block_ll
 from repro.kernels.suffstats import MAX_KERNEL_D  # shared VMEM ceiling
-
-LOG_2PI = 1.8378770664093453
 
 
 def _loglik_kernel(x_ref, mu_ref, f_ref, ld_ref, o_ref):
-    x = x_ref[...]                               # (bn, d)
-    mu = mu_ref[...]                             # (bk, d)
-    f = f_ref[...]                               # (bk, d, d)
-    ld = ld_ref[...]                             # (bk,)
-    d = x.shape[-1]
-    diff = x[:, None, :] - mu[None, :, :]        # (bn, bk, d)
-    # batched whitening matmul on the MXU: (bk, bn, d) @ (bk, d, d)
-    y = jax.lax.dot_general(
-        diff.transpose(1, 0, 2), f,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)      # (bk, bn, d)
-    maha = jnp.sum(y * y, axis=-1)               # (bk, bn)
-    o_ref[...] = (0.5 * (ld[:, None] - maha)
-                  - 0.5 * d * LOG_2PI).T.astype(o_ref.dtype)
+    d = x_ref.shape[-1]
+    o_ref[...] = gauss_block_ll(x_ref[...], mu_ref[...], f_ref[...],
+                                ld_ref[...], d).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bk", "interpret"))
@@ -67,18 +55,19 @@ def loglik(x: jax.Array, mu: jax.Array, chol_prec: jax.Array,
         logdet_prec = jnp.pad(logdet_prec, (0, pk))
     gn, gk = x.shape[0] // bn, mu.shape[0] // bk
 
+    # the output leaves as (K/bk, N, bk) tiles — whole last dims, so any bk
+    # is a legal block — and is laid back out to (N, K) by XLA
     out = pl.pallas_call(
         _loglik_kernel,
         grid=(gn, gk),
         in_specs=[
             pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((bk, d), lambda i, j: (j, 0)),
+            pl.BlockSpec((bk, 1, d), lambda i, j: (j, 0, 0)),
             pl.BlockSpec((bk, d, d), lambda i, j: (j, 0, 0)),
-            pl.BlockSpec((bk,), lambda i, j: (j,)),
+            col_spec(bk, lambda i, j: j),
         ],
-        out_specs=pl.BlockSpec((bn, bk), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((x.shape[0], mu.shape[0]),
-                                       jnp.float32),
+        out_specs=pl.BlockSpec((None, bn, bk), lambda i, j: (j, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((gk, x.shape[0], bk), jnp.float32),
         interpret=interpret,
-    )(x, mu, chol_prec, logdet_prec)
-    return out[:n, :k]
+    )(x, mu[:, None, :], chol_prec, _cols(logdet_prec, bk))
+    return out.transpose(1, 0, 2).reshape(x.shape[0], -1)[:n, :k]

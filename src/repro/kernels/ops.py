@@ -31,22 +31,35 @@ MATMUL_CROSSOVER = 640_000
 # shared VMEM ceiling on the feature dim (kernels/loglik.py, suffstats.py)
 MAX_KERNEL_D = _suffstats.MAX_KERNEL_D
 
-# VMEM budget for the resident (K, 2, ...) sub-cluster parameter block of
-# the fused sub-assignment kernels (kernels/assign.py) — Cholesky factors
-# for the Gaussian, packed weights (+ the per-tile (bn, K) one-hot used for
-# the MXU gather) for the linear families. Only the three-pass step-(f)
-# kernels still hold an all-K block; the megakernels stream K-blocks.
-SUB_PARAMS_VMEM_BYTES = 8 * 1024 * 1024
-
-# Per-GRID-STEP VMEM budget for the K-blocked kernels (assign + megakernel
-# sweeps): only a (bn, ...) point block and a (bk, ...) cluster tile are
-# resident at once, so the guard scales with bk — NOT with K — and the
-# effective K and d ceilings are set by HBM, not VMEM. This replaces the
-# old blanket ``MAX_KERNEL_D``/all-K-resident guards for those kernels.
+# Per-GRID-STEP VMEM budget for the K-blocked kernels (assign, sub-assign
+# and the megakernel sweeps): only a (bn, ...) point block and a (bk, ...)
+# cluster tile are resident at once, so each guard scales with bk — NOT
+# with K — and the effective K and d ceilings are set by HBM, not VMEM.
+# The estimates count Pallas' two pipeline buffers per input/output block
+# plus the kernel's largest in-register intermediates.
 KERNEL_BLOCK_VMEM_BYTES = 8 * 1024 * 1024
+
+
+def _fits(floats: int) -> bool:
+    return floats * 4 <= KERNEL_BLOCK_VMEM_BYTES
+
 
 # Default streamed cluster-tile size (see kernels/sweep.py)
 K_BLOCK = _sweep.K_BLOCK
+
+# HBM budget for the stat partials of one megakernel call. The sweeps
+# write one (2K, ...) partial slab per 128-point block, as large as the
+# fit's stat accumulator, so ComponentFamily.sweep runs longer inputs in
+# STATS_BLOCK-aligned chunks (core/family.fold_chunked).
+SWEEP_PARTIALS_BYTES = 256 * 1024 * 1024
+
+
+def sweep_chunk_points(acc_bytes: int) -> int:
+    """Points per megakernel call whose partials fit the budget, given the
+    bytes of the (K, 2, ...) stat accumulator."""
+    per_stats_block = acc_bytes * (_sweep.STATS_BLOCK // 128)
+    return _sweep.STATS_BLOCK * max(1, SWEEP_PARTIALS_BYTES
+                                    // per_stats_block)
 
 
 def _interpret() -> bool:
@@ -97,10 +110,9 @@ def gauss_loglik(x: jax.Array, params, use_pallas: bool) -> jax.Array:
 def assign_linear_pallas(feats, w, const, logw, active, gidx, key_data,
                          slots=None, k_block: int = K_BLOCK
                          ) -> Optional[jax.Array]:
-    bn, bk = 128, k_block
-    # per grid step: (bn, d') feats + (bk, d') weight tile + (bn, bk) logits
-    step = (bn * feats.shape[1] + bk * feats.shape[1] + 3 * bn * bk) * 4
-    if step > KERNEL_BLOCK_VMEM_BYTES:
+    bn, bk, dp = 128, k_block, feats.shape[1]
+    # (bn, d') feats + (bk, d') weight tile, double-buffered; logit tiles
+    if not _fits(2 * (bn * dp + bk * dp) + 3 * bn * bk):
         return None
     return _assign.assign_linear(feats, w, const, logw, active, gidx,
                                  key_data, slots, bk=bk,
@@ -111,10 +123,10 @@ def assign_gauss_pallas(x, mu, chol_prec, logdet_prec, logw, active, gidx,
                         key_data, slots=None, k_block: int = K_BLOCK
                         ) -> Optional[jax.Array]:
     bn, bk, d = 128, k_block, x.shape[1]
-    # per grid step: (bn, d) x + (bk, d, d) Cholesky tile + (bn, bk, d)
-    # whitened diffs (x2 for the transpose staging)
-    step = (bn * d + bk * d * d + 2 * bn * bk * d + 3 * bn * bk) * 4
-    if step > KERNEL_BLOCK_VMEM_BYTES:
+    # (bn, d) x + (bk, d, d) Cholesky tile, double-buffered; the
+    # (bk, bn, d) diffs and whitened diffs; logit tiles
+    if not _fits(2 * (bn * d + bk * d + bk * d * d) + 2 * bk * bn * d
+                 + 3 * bn * bk):
         return None
     return _assign.assign_gauss(x, mu, chol_prec, logdet_prec, logw,
                                 active, gidx, key_data, slots, bk=bk,
@@ -122,22 +134,29 @@ def assign_gauss_pallas(x, mu, chol_prec, logdet_prec, logw, active, gidx,
 
 
 def sub_assign_linear_pallas(feats, w, const, sublogw, labels, gidx,
-                             key_data) -> Optional[jax.Array]:
-    resident = (w.size + 128 * w.shape[0]) * 4   # (K,2,d') block + one-hot
-    if feats.shape[1] > 2 * MAX_KERNEL_D or resident > SUB_PARAMS_VMEM_BYTES:
+                             key_data, k_block: int = K_BLOCK
+                             ) -> Optional[jax.Array]:
+    bn, bk, dp = 128, k_block, feats.shape[1]
+    # (bn, d') feats + (2bk, d') sub-weight tile, double-buffered; the
+    # (bn, 2bk) likelihood / select tiles
+    if not _fits(2 * (bn * dp + 2 * bk * dp) + 8 * bn * bk):
         return None
     return _assign.sub_assign_linear(feats, w, const, sublogw, labels,
-                                     gidx, key_data,
+                                     gidx, key_data, bk=bk,
                                      interpret=_interpret())
 
 
 def sub_assign_gauss_pallas(x, mu, chol_prec, logdet_prec, sublogw, labels,
-                            gidx, key_data) -> Optional[jax.Array]:
-    d = x.shape[1]
-    if d > MAX_KERNEL_D or chol_prec.size * 4 > SUB_PARAMS_VMEM_BYTES:
+                            gidx, key_data, k_block: int = K_BLOCK
+                            ) -> Optional[jax.Array]:
+    bn, bk, d = 128, k_block, x.shape[1]
+    # (bn, d) x + (2bk, d, d) sub-Cholesky tile, double-buffered; the
+    # (2bk, bn, d) diffs and whitened diffs; select tiles
+    if not _fits(2 * (bn * d + 2 * bk * d + 2 * bk * d * d)
+                 + 4 * bk * bn * d + 8 * bn * bk):
         return None
     return _assign.sub_assign_gauss(x, mu, chol_prec, logdet_prec, sublogw,
-                                    labels, gidx, key_data,
+                                    labels, gidx, key_data, bk=bk,
                                     interpret=_interpret())
 
 
@@ -148,17 +167,17 @@ def sweep_linear_pallas(feats, w, const, logw, active, subw, subconst,
     families.
 
     Returns ``(labels, sublabels, n2, sf2)`` with per-STATS_BLOCK stat
-    partials, or ``None`` outside the per-K-block VMEM envelope (caller
-    falls back to the blocked jnp reference). Only a (bk, ...) cluster
-    tile is resident per grid step, so the guard is independent of K.
+    partials, or ``None`` outside the per-K-block VMEM envelope (the
+    caller records the fallback to the blocked jnp reference). Only a
+    (bk, ...) cluster tile is resident per grid step, so the guard is
+    independent of K.
     """
     bn, bk, dp = 128, k_block, feats.shape[1]
-    # per grid step: (bn, d') feats, (bk, d') + (bk, 2, d') weight tiles,
-    # the (bn, bk) one-hot / (bn, 2bk) segment one-hot, the (2bk, d') stat
-    # partial tile and the (bn, 2, d') gathered sub-weights
-    step = (bn * dp + 3 * bk * dp + 5 * bn * bk + 2 * bk * dp
-            + 2 * bn * dp) * 4
-    if step > KERNEL_BLOCK_VMEM_BYTES:
+    # double-buffered: (bn, d') feats, (bk, d') + (2bk, d') weight tiles,
+    # the (2bk, d') stat partial tile; plus the (bn, bk) / (bn, 2bk)
+    # logit, select and segment one-hot tiles
+    if not _fits(2 * (bn * dp + 3 * bk * dp) + 2 * 2 * bk * dp
+                 + 8 * bn * bk):
         return None
     return _sweep.sweep_linear(feats, w, const, logw, active, subw,
                                subconst, sublogw, valid, gidx, key_z,
@@ -172,12 +191,14 @@ def sweep_gauss_pallas(x, mu, chol_prec, logdet_prec, logw, active, sub_mu,
     """One-read, K-blocked fused sweep for the full-covariance Gaussian,
     or ``None`` outside the per-K-block VMEM envelope."""
     bn, bk, d = 128, k_block, x.shape[1]
-    # per grid step: (bk, d, d) + (bk, 2, d, d) Cholesky tiles, the
-    # gathered (bn, 2, d, d) factors, (bn, bk, d) diffs (x2 staging) and
-    # the (2bk, d, d) stat partial tile
-    step = (bn * d + 3 * bk * d * d + 2 * bn * d * d + 2 * bn * bk * d
-            + 2 * bk * d * d + 5 * bn * bk) * 4
-    if step > KERNEL_BLOCK_VMEM_BYTES:
+    # double-buffered: (bn, d) x, the (bk, d, d) + (2bk, d, d) Cholesky
+    # tiles with their means, the (2bk, d) + (2bk, d, d) stat partial
+    # tiles; plus phase 1's (2bk, bn, d) diffs and whitened diffs, the
+    # (2bk, bn, d) second-moment staging (x3: weight, transpose,
+    # broadcast) and the logit / one-hot tiles
+    if not _fits(2 * (bn * d + 3 * bk * d + 3 * bk * d * d)
+                 + 2 * (2 * bk * d + 2 * bk * d * d)
+                 + 10 * bk * bn * d + 10 * bn * bk):
         return None
     return _sweep.sweep_gauss(x, mu, chol_prec, logdet_prec, logw, active,
                               sub_mu, sub_chol_prec, sub_logdet_prec,

@@ -65,14 +65,17 @@ def uniform01(bits: jax.Array) -> jax.Array:
     Uses the top 24 bits at bin centers: u = (bits>>8 + 0.5) / 2^24, so
     u in [2^-25, 1 - 2^-25] and log(u), log(-log(u)) are always finite.
     """
-    top = (bits >> jnp.uint32(8)).astype(jnp.float32)
+    # 24-bit values convert exactly through int32 (Mosaic has no
+    # uint32 -> f32 conversion; the result is the same float either way)
+    top = (bits >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32)
     return (top + 0.5) * jnp.float32(1.0 / (1 << 24))
 
 
 def gumbel(key_data: jax.Array, c0: jax.Array, c1: jax.Array) -> jax.Array:
     """Standard Gumbel noise keyed by counters (c0, c1); broadcasts.
 
-    ``key_data``: (2,) uint32 raw key words (``jax.random.key_data``).
+    ``key_data``: (2,) uint32 raw key words (``jax.random.key_data``), or
+    a kernel's SMEM ref holding them (indexing reads the two scalars).
     """
     b0, _ = threefry2x32(key_data[0], key_data[1], c0, c1)
     return -jnp.log(-jnp.log(uniform01(b0)))

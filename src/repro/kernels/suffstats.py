@@ -23,7 +23,10 @@ Two generations of kernel live here:
 Tiling: grid (S/bk, N/bn) with the N axis innermost and *revisited*: the
 output tiles stay resident in VMEM and accumulate across N steps — the TPU
 analogue of the paper's per-stream partial sums, with the cross-device psum
-happening outside the kernel.
+happening outside the kernel. Labels travel as (N, 1) columns and counts
+leave as (S/bk, 1, bk) rows, so every block's last two dims are
+tile-aligned or whole, as Mosaic requires; the dense kernel takes resp
+transposed, (K, N), for the same reason.
 VMEM (bk=8, bn=128, d<=128): x 64k + resp 4k + sxx 512k + masked 512k f32.
 ``MAX_KERNEL_D`` guards that budget: the (bk, d, d) output tile and the
 (bk, bn, d) masked intermediate grow as d^2 / d, so d > 128 would blow the
@@ -47,68 +50,64 @@ from repro.kernels import ref
 MAX_KERNEL_D = 128
 
 
-def _suffstats_kernel(x_ref, r_ref, n_ref, sx_ref, sxx_ref):
+def _suffstats_kernel(x_ref, rt_ref, n_ref, sx_ref, sxx_ref):
     @pl.when(pl.program_id(1) == 0)
     def _init():
         n_ref[...] = jnp.zeros_like(n_ref)
         sx_ref[...] = jnp.zeros_like(sx_ref)
         sxx_ref[...] = jnp.zeros_like(sxx_ref)
 
-    x = x_ref[...]                                   # (bn, d)
-    r = r_ref[...]                                   # (bn, bk)
-    n_ref[...] += jnp.sum(r, axis=0)
+    _accumulate(x_ref[...], rt_ref[...].T, n_ref, sx_ref, sxx_ref)
+
+
+def _accumulate(x, r, n_ref, sx_ref, sxx_ref=None):
+    """Fold one (bn, d) point tile into the resident (bk, ...) stat tiles
+    given its (bn, bk) responsibility tile — the op order of the sweep
+    megakernels' stat fold (kernels/sweep.py), so both paths add the same
+    floats."""
+    n_ref[...] += jnp.sum(r, axis=0, keepdims=True)
     sx_ref[...] += jnp.dot(r.T, x, preferred_element_type=jnp.float32)
-    # masked points per cluster: (bk, bn, d), then batched x^T x on the MXU
-    xw = r.T[:, :, None] * x[None, :, :]             # (bk, bn, d)
-    sxx_ref[...] += jax.lax.dot_general(
-        xw.transpose(0, 2, 1), jnp.broadcast_to(x, (r.shape[1],) + x.shape),
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)          # (bk, d, d)
+    if sxx_ref is not None:
+        # masked points per cluster: (bk, bn, d), then batched x^T x on
+        # the MXU
+        xw = r.T[:, :, None] * x[None, :, :]         # (bk, bn, d)
+        sxx_ref[...] += jax.lax.dot_general(
+            xw.transpose(0, 2, 1),
+            jnp.broadcast_to(x, (r.shape[1],) + x.shape),
+            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)      # (bk, d, d)
 
 
-def _tile_resp(lab_ref, sub_ref, val_ref, j: int, bk: int) -> jax.Array:
-    """(bn, bk) one-hot over segments s = 2*label + sublabel, in VMEM."""
-    seg = lab_ref[...] * 2 + sub_ref[...]            # (bn,)
-    col = (jnp.int32(j * bk)
-           + jax.lax.broadcasted_iota(jnp.int32, (seg.shape[0], bk), 1))
-    return ((seg[:, None] == col).astype(jnp.float32)
-            * val_ref[...][:, None])
+def _tile_resp(lab_ref, sub_ref, val_ref, j, bk: int) -> jax.Array:
+    """(bn, bk) one-hot over segments s = 2*label + sublabel, in VMEM,
+    from the (bn, 1) label columns."""
+    seg = lab_ref[...] * 2 + sub_ref[...]            # (bn, 1)
+    col = j * bk + jax.lax.broadcasted_iota(jnp.int32, (seg.shape[0], bk), 1)
+    return (seg == col).astype(jnp.float32) * val_ref[...]
 
 
 def _suffstats_labels_kernel(x_ref, lab_ref, sub_ref, val_ref,
                              n_ref, sx_ref, sxx_ref):
-    r_ref = _tile_resp(lab_ref, sub_ref, val_ref, pl.program_id(0),
-                       n_ref.shape[0])
-
     @pl.when(pl.program_id(1) == 0)
     def _init():
         n_ref[...] = jnp.zeros_like(n_ref)
         sx_ref[...] = jnp.zeros_like(sx_ref)
         sxx_ref[...] = jnp.zeros_like(sxx_ref)
 
-    x = x_ref[...]
-    r = r_ref
-    n_ref[...] += jnp.sum(r, axis=0)
-    sx_ref[...] += jnp.dot(r.T, x, preferred_element_type=jnp.float32)
-    xw = r.T[:, :, None] * x[None, :, :]
-    sxx_ref[...] += jax.lax.dot_general(
-        xw.transpose(0, 2, 1), jnp.broadcast_to(x, (r.shape[1],) + x.shape),
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
+    r = _tile_resp(lab_ref, sub_ref, val_ref, pl.program_id(0),
+                   n_ref.shape[1])
+    _accumulate(x_ref[...], r, n_ref, sx_ref, sxx_ref)
 
 
 def _moments_labels_kernel(x_ref, lab_ref, sub_ref, val_ref, n_ref, sx_ref):
-    r = _tile_resp(lab_ref, sub_ref, val_ref, pl.program_id(0),
-                   n_ref.shape[0])
-
     @pl.when(pl.program_id(1) == 0)
     def _init():
         n_ref[...] = jnp.zeros_like(n_ref)
         sx_ref[...] = jnp.zeros_like(sx_ref)
 
-    n_ref[...] += jnp.sum(r, axis=0)
-    sx_ref[...] += jnp.dot(r.T, x_ref[...],
-                           preferred_element_type=jnp.float32)
+    r = _tile_resp(lab_ref, sub_ref, val_ref, pl.program_id(0),
+                   n_ref.shape[1])
+    _accumulate(x_ref[...], r, n_ref, sx_ref)
 
 
 def _pad_points(arrs, bn: int):
@@ -121,6 +120,14 @@ def _pad_points(arrs, bn: int):
         widths = [(0, pn)] + [(0, 0)] * (a.ndim - 1)
         out.append(jnp.pad(a, widths))
     return out
+
+
+def _label_cols(labels, sublabels, valid, bn: int):
+    """Per-point label vectors as (N, 1) columns in (bn, 1) blocks."""
+    arrs = _pad_points((labels.astype(jnp.int32), sublabels.astype(jnp.int32),
+                        jnp.asarray(valid, jnp.float32)), bn)
+    spec = pl.BlockSpec((bn, 1), lambda j, i: (i, 0))
+    return [a[:, None] for a in arrs], [spec] * 3
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bk", "interpret"))
@@ -147,21 +154,30 @@ def suffstats(x: jax.Array, resp: jax.Array, *, bn: int = 128, bk: int = 8,
         grid=(gk, gn),                       # N innermost: accumulation
         in_specs=[
             pl.BlockSpec((bn, d), lambda j, i: (i, 0)),
-            pl.BlockSpec((bn, bk), lambda j, i: (i, j)),
+            pl.BlockSpec((bk, bn), lambda j, i: (j, i)),
         ],
-        out_specs=[
-            pl.BlockSpec((bk,), lambda j, i: (j,)),
-            pl.BlockSpec((bk, d), lambda j, i: (j, 0)),
-            pl.BlockSpec((bk, d, d), lambda j, i: (j, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((resp.shape[1],), jnp.float32),
-            jax.ShapeDtypeStruct((resp.shape[1], d), jnp.float32),
-            jax.ShapeDtypeStruct((resp.shape[1], d, d), jnp.float32),
-        ],
+        out_specs=_stat_specs(bk, d, True),
+        out_shape=_stat_shapes(resp.shape[1], bk, d, True),
         interpret=interpret,
-    )(x, resp)
-    return n_out[:k], sx[:k], sxx[:k]
+    )(x, resp.T)
+    return n_out.reshape(-1)[:k], sx[:k], sxx[:k]
+
+
+def _stat_specs(bk: int, d: int, second: bool):
+    # counts leave as (S/bk, 1, bk) rows: whole last two block dims
+    specs = [pl.BlockSpec((None, 1, bk), lambda j, i: (j, 0, 0)),
+             pl.BlockSpec((bk, d), lambda j, i: (j, 0))]
+    if second:
+        specs.append(pl.BlockSpec((bk, d, d), lambda j, i: (j, 0, 0)))
+    return specs
+
+
+def _stat_shapes(s: int, bk: int, d: int, second: bool):
+    shapes = [jax.ShapeDtypeStruct((s // bk, 1, bk), jnp.float32),
+              jax.ShapeDtypeStruct((s, d), jnp.float32)]
+    if second:
+        shapes.append(jax.ShapeDtypeStruct((s, d, d), jnp.float32))
+    return shapes
 
 
 @functools.partial(jax.jit,
@@ -183,33 +199,20 @@ def suffstats_labels(x: jax.Array, labels: jax.Array, sublabels: jax.Array,
     s = 2 * k
     bn = min(bn, n_pts) or 1
     bk = min(bk, s)
-    x, labels, sublabels, valid = _pad_points(
-        (x, labels, sublabels, jnp.asarray(valid, jnp.float32)), bn)
+    (x,) = _pad_points((x,), bn)
+    cols, col_specs = _label_cols(labels, sublabels, valid, bn)
     ps = (-s) % bk
     gk, gn = (s + ps) // bk, x.shape[0] // bn
 
     n2, sx2, sxx2 = pl.pallas_call(
         _suffstats_labels_kernel,
         grid=(gk, gn),
-        in_specs=[
-            pl.BlockSpec((bn, d), lambda j, i: (i, 0)),
-            pl.BlockSpec((bn,), lambda j, i: (i,)),
-            pl.BlockSpec((bn,), lambda j, i: (i,)),
-            pl.BlockSpec((bn,), lambda j, i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bk,), lambda j, i: (j,)),
-            pl.BlockSpec((bk, d), lambda j, i: (j, 0)),
-            pl.BlockSpec((bk, d, d), lambda j, i: (j, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((s + ps,), jnp.float32),
-            jax.ShapeDtypeStruct((s + ps, d), jnp.float32),
-            jax.ShapeDtypeStruct((s + ps, d, d), jnp.float32),
-        ],
+        in_specs=[pl.BlockSpec((bn, d), lambda j, i: (i, 0)), *col_specs],
+        out_specs=_stat_specs(bk, d, True),
+        out_shape=_stat_shapes(s + ps, bk, d, True),
         interpret=interpret,
-    )(x, labels, sublabels, valid)
-    return (n2[:s].reshape(k, 2), sx2[:s].reshape(k, 2, d),
+    )(x, *cols)
+    return (n2.reshape(-1)[:s].reshape(k, 2), sx2[:s].reshape(k, 2, d),
             sxx2[:s].reshape(k, 2, d, d))
 
 
@@ -231,28 +234,17 @@ def moments_labels(feats: jax.Array, labels: jax.Array,
     s = 2 * k
     bn = min(bn, n_pts) or 1
     bk = min(bk, s)
-    feats, labels, sublabels, valid = _pad_points(
-        (feats, labels, sublabels, jnp.asarray(valid, jnp.float32)), bn)
+    (feats,) = _pad_points((feats,), bn)
+    cols, col_specs = _label_cols(labels, sublabels, valid, bn)
     ps = (-s) % bk
     gk, gn = (s + ps) // bk, feats.shape[0] // bn
 
     n2, sf2 = pl.pallas_call(
         _moments_labels_kernel,
         grid=(gk, gn),
-        in_specs=[
-            pl.BlockSpec((bn, dp), lambda j, i: (i, 0)),
-            pl.BlockSpec((bn,), lambda j, i: (i,)),
-            pl.BlockSpec((bn,), lambda j, i: (i,)),
-            pl.BlockSpec((bn,), lambda j, i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bk,), lambda j, i: (j,)),
-            pl.BlockSpec((bk, dp), lambda j, i: (j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((s + ps,), jnp.float32),
-            jax.ShapeDtypeStruct((s + ps, dp), jnp.float32),
-        ],
+        in_specs=[pl.BlockSpec((bn, dp), lambda j, i: (i, 0)), *col_specs],
+        out_specs=_stat_specs(bk, dp, False),
+        out_shape=_stat_shapes(s + ps, bk, dp, False),
         interpret=interpret,
-    )(feats, labels, sublabels, valid)
-    return n2[:s].reshape(k, 2), sf2[:s].reshape(k, 2, dp)
+    )(feats, *cols)
+    return n2.reshape(-1)[:s].reshape(k, 2), sf2[:s].reshape(k, 2, dp)

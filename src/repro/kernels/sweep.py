@@ -14,8 +14,9 @@ resident in VMEM it is
  1. assigned (step e: loglik + log pi + counter-based Threefry Gumbel,
     a flash-attention-style running argmax over *streamed* (bk, ...)
     cluster tiles — never the full (K, ...) slab),
- 2. sub-assigned under its OWN cluster only (step f: one-hot MXU gather /
-    vector ``take`` of the owning K-block's (bk, 2, ...) sub-params), and
+ 2. sub-assigned under its OWN cluster only (step f: the owning K-block's
+    2*bk sub-cluster likelihoods on the MXU, each point keeping its own
+    cluster's pair by an exact masked select — no in-kernel gather), and
  3. folded into per-(point-block, K-block) stat partial tiles
 
 — labels, sub-labels, and the stat partials stream out; the block of
@@ -55,8 +56,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import prng
-from repro.kernels.assign import LOG_2PI, NEG_INF, _fold_best, _pad_dim
+from repro.kernels.assign import (KEY_SPEC, NEG_INF, _cluster_vectors,
+                                  _cols, _fold_best, _noisy_logits, _pad_dim,
+                                  _rows, col_spec, gauss_block_ll,
+                                  linear_sub_ll, own_sub_labels, row_spec)
 
 # Granularity of the suff-stat fold — the system-wide contract (re-exported
 # by core/gibbs.py): partial stats are produced per STATS_BLOCK points and
@@ -99,13 +102,40 @@ def _fold_stats(a: jax.Array, spb: int) -> jax.Array:
 def _seg_onehot_block(loc, sub, valid, s: int):
     """(bn, 2*bk) one-hot over the K-block's segments 2*loc + sub.
 
-    ``loc`` is the block-local label; rows owned by other K-blocks fall
-    outside [0, s) and contribute all-zero rows, so the per-column sums
-    are exactly the full-width one-hot's columns for this block.
+    ``loc``/``sub``/``valid`` are (bn, 1) columns; ``loc`` is the
+    block-local label, so rows owned by other K-blocks fall outside
+    [0, s) and contribute all-zero rows: the per-column sums are exactly
+    the full-width one-hot's columns for this block.
     """
     seg = loc * 2 + sub
     col = jax.lax.broadcasted_iota(jnp.int32, (seg.shape[0], s), 1)
-    return (seg[:, None] == col).astype(jnp.float32) * valid[:, None]
+    return (seg == col).astype(jnp.float32) * valid
+
+
+def _sweep_specs(bn: int):
+    """Point-side specs shared by both megakernels: the (bn, 1) columns of
+    valid / gidx, the two SMEM key pairs, and the outputs' label columns."""
+    point = pl.BlockSpec((bn, 1), lambda i, p, j: (i, 0))
+    return [point, point, KEY_SPEC, KEY_SPEC], [point, point, point]
+
+
+def _label_shapes(n_pad: int):
+    return [jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n_pad, 1), jnp.int32)]
+
+
+# Stat partial outputs: one (2bk, ...) tile per (point block, K block).
+# The block index is held at (i, 0) through phase 0, then visits (i, j)
+# once in phase 1; counts ride in (gn, gk, 1, 2bk) so the block's last two
+# dims are whole.
+def _n_spec(sb: int) -> pl.BlockSpec:
+    return pl.BlockSpec((None, None, 1, sb), lambda i, p, j: (i, j * p, 0, 0))
+
+
+def _stat_spec(sb: int, *tail: int) -> pl.BlockSpec:
+    zeros = (0,) * len(tail)
+    return pl.BlockSpec((None, sb) + tail, lambda i, p, j: (i, j * p) + zeros)
 
 
 # ---------------------------------------------------------------------------
@@ -136,43 +166,25 @@ def _sweep_linear_kernel(feats_ref, w_ref, const_ref, logw_ref, act_ref,
         # strict first-max fold) — bitwise the full argmax.
         ll = (jnp.dot(feats, w_ref[...].T,
                       preferred_element_type=jnp.float32)
-              + const_ref[...][None, :])
-        t = ll + logw_ref[...][None, :]
-        t = jnp.where(act_ref[...][None, :] != 0, t, NEG_INF)
-        cid = jnp.broadcast_to(slot_ref[...][None, :], t.shape)
-        t = t + prng.gumbel(kz_ref[...], gidx[:, None], cid)
+              + const_ref[...])
+        t = _noisy_logits(ll, logw_ref[...], act_ref[...], slot_ref[...],
+                          kz_ref, gidx)
         _fold_best(j, bk, t, best_ref, lab_ref)
 
     @pl.when(p == 1)
     def _sub_and_stats():
-        # step (f) + stat fold for the points THIS K-block owns
-        lab = lab_ref[...]
-        loc = lab - j * bk                               # block-local label
+        # step (f) + stat fold for the points THIS K-block owns: the
+        # block's (bn, 2bk) sub-likelihoods, each point keeping its own
+        # cluster's pair (kernels/assign.sub_assign_linear)
+        loc = lab_ref[...] - j * bk                      # block-local label
         in_blk = (loc >= 0) & (loc < bk)
-        dp = feats.shape[1]
-        onehot = (loc[:, None]
-                  == jax.lax.broadcasted_iota(jnp.int32,
-                                              (lab.shape[0], bk), 1)
-                  ).astype(jnp.float32)                  # 0 rows off-block
-        own_w = jnp.dot(onehot, subw_ref[...].reshape(bk, 2 * dp),
-                        preferred_element_type=jnp.float32
-                        ).reshape(-1, 2, dp)
-        own_const = jnp.dot(onehot, subconst_ref[...],
-                            preferred_element_type=jnp.float32)
-        own_logw = jnp.dot(onehot, sublogw_ref[...],
-                           preferred_element_type=jnp.float32)
-        ll = jnp.einsum("nd,nsd->ns", feats, own_w,
-                        preferred_element_type=jnp.float32) + own_const
-        t = ll + own_logw
-        cid = jax.lax.broadcasted_iota(jnp.uint32, t.shape, 1)
-        t = t + prng.gumbel(kzb_ref[...], gidx[:, None], cid)
-        sub = jnp.argmax(t, axis=1).astype(jnp.int32)
+        ll2 = linear_sub_ll(feats, subw_ref[...], subconst_ref[...])
+        sub = own_sub_labels(ll2, sublogw_ref[...], loc, kzb_ref, gidx)
         sub = jnp.where(in_blk, sub, sub_ref[...])
         sub_ref[...] = sub
         r = _seg_onehot_block(loc, sub, valid_ref[...], n_ref.shape[1])
-        n_ref[...] = jnp.sum(r, axis=0)[None, :]
-        sf_ref[...] = jnp.dot(r.T, feats,
-                              preferred_element_type=jnp.float32)[None]
+        n_ref[...] = jnp.sum(r, axis=0, keepdims=True)
+        sf_ref[...] = jnp.dot(r.T, feats, preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bk", "interpret"))
@@ -203,24 +215,17 @@ def sweep_linear(feats: jax.Array, w: jax.Array, const: jax.Array,
         slots = jnp.arange(k, dtype=jnp.uint32)
     bk = min(bk, k) or 1
     feats, valid, gidx = _pad_points(
-        (feats, jnp.asarray(valid, jnp.float32),
-         gidx.astype(jnp.uint32)), bn)
+        (feats, jnp.asarray(valid, jnp.float32)[:, None],
+         gidx.astype(jnp.uint32)[:, None]), bn)
     pk = (-k) % bk
-    w = _pad_dim(w, 0, pk)
-    const = _pad_dim(const, 0, pk)
-    logw = _pad_dim(logw, 0, pk)
-    active = _pad_dim(active.astype(jnp.int32), 0, pk)   # pad slots inactive
-    slots = _pad_dim(slots.astype(jnp.uint32), 0, pk)
-    subw = _pad_dim(subw, 0, pk)
-    subconst = _pad_dim(subconst, 0, pk)
-    sublogw = _pad_dim(sublogw, 0, pk)
-    k_pad = w.shape[0]
-    s = 2 * k_pad
+    k_pad = k + pk
     sb = 2 * bk
     gn = feats.shape[0] // bn
     gk = k_pad // bk
     spb = STATS_BLOCK // bn
     nsb = -(-gn // spb)
+    blk = lambda i, p, j: j
+    point_in, point_out = _sweep_specs(bn)
 
     _, labels, sublabels, n2, sf2 = pl.pallas_call(
         _sweep_linear_kernel,
@@ -228,43 +233,32 @@ def sweep_linear(feats: jax.Array, w: jax.Array, const: jax.Array,
         in_specs=[
             pl.BlockSpec((bn, dp), lambda i, p, j: (i, 0)),
             pl.BlockSpec((bk, dp), lambda i, p, j: (j, 0)),   # streamed tile
-            pl.BlockSpec((bk,), lambda i, p, j: (j,)),
-            pl.BlockSpec((bk,), lambda i, p, j: (j,)),
-            pl.BlockSpec((bk,), lambda i, p, j: (j,)),
-            pl.BlockSpec((bk,), lambda i, p, j: (j,)),
-            pl.BlockSpec((bk, 2, dp), lambda i, p, j: (j, 0, 0)),
-            pl.BlockSpec((bk, 2), lambda i, p, j: (j, 0)),
-            pl.BlockSpec((bk, 2), lambda i, p, j: (j, 0)),
-            pl.BlockSpec((bn,), lambda i, p, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, p, j: (i,)),
-            pl.BlockSpec((2,), lambda i, p, j: (0,)),
-            pl.BlockSpec((2,), lambda i, p, j: (0,)),
+            row_spec(bk, blk), row_spec(bk, blk), row_spec(bk, blk),
+            row_spec(bk, blk),
+            pl.BlockSpec((sb, dp), lambda i, p, j: (j, 0)),
+            row_spec(sb, blk), row_spec(sb, blk),
+            *point_in,
         ],
-        out_specs=[
-            pl.BlockSpec((bn,), lambda i, p, j: (i,)),   # revisited (i fixed)
-            pl.BlockSpec((bn,), lambda i, p, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, p, j: (i,)),
-            # held at (i, 0) through phase 0, then single-visit (i, j)
-            pl.BlockSpec((1, sb), lambda i, p, j: (i, j * p)),
-            pl.BlockSpec((1, sb, dp), lambda i, p, j: (i, j * p, 0)),
-        ],
+        out_specs=[*point_out, _n_spec(sb), _stat_spec(sb, dp)],
         out_shape=[
-            jax.ShapeDtypeStruct((feats.shape[0],), jnp.float32),
-            jax.ShapeDtypeStruct((feats.shape[0],), jnp.int32),
-            jax.ShapeDtypeStruct((feats.shape[0],), jnp.int32),
-            jax.ShapeDtypeStruct((gn, s), jnp.float32),
-            jax.ShapeDtypeStruct((gn, s, dp), jnp.float32),
+            *_label_shapes(feats.shape[0]),
+            jax.ShapeDtypeStruct((gn, gk, 1, sb), jnp.float32),
+            jax.ShapeDtypeStruct((gn, 2 * k_pad, dp), jnp.float32),
         ],
         interpret=interpret,
-    )(feats, w, const, logw, active, slots, subw, subconst, sublogw,
+    )(feats, _pad_dim(w, 0, pk), _rows(_pad_dim(const, 0, pk), bk),
+      *_cluster_vectors(bk, pk, logw, active, slots),
+      _pad_dim(subw, 0, pk).reshape(-1, dp),
+      _rows(_pad_dim(subconst, 0, pk).reshape(-1), sb),
+      _rows(_pad_dim(sublogw, 0, pk).reshape(-1), sb),
       valid, gidx, key_z, key_zb)
-    n2 = _fold_stats(n2, spb).reshape(nsb, k_pad, 2)[:, :k]
+    n2 = _fold_stats(n2.reshape(gn, -1), spb).reshape(nsb, k_pad, 2)[:, :k]
     sf2 = _fold_stats(sf2, spb).reshape(nsb, k_pad, 2, dp)[:, :k]
-    return labels[:n], sublabels[:n], n2, sf2
+    return labels[:n, 0], sublabels[:n, 0], n2, sf2
 
 
 # ---------------------------------------------------------------------------
-# Full-covariance Gaussian: whitening-Mahalanobis assignment, vector-gather
+# Full-covariance Gaussian: whitening-Mahalanobis assignment, own-cluster
 # sub-assignment, second-moment stat fold — one resident x block, streamed
 # (bk, d, d) Cholesky tiles.
 # ---------------------------------------------------------------------------
@@ -274,7 +268,7 @@ def _sweep_gauss_kernel(x_ref, mu_ref, f_ref, ld_ref, logw_ref, act_ref,
                         best_ref, lab_ref, sub_ref, n_ref, sx_ref, sxx_ref):
     p = pl.program_id(1)
     j = pl.program_id(2)
-    bk, d = mu_ref.shape
+    bk, _, d = mu_ref.shape
     x = x_ref[...]                                       # the ONE x read
     gidx = gidx_ref[...]
 
@@ -288,56 +282,35 @@ def _sweep_gauss_kernel(x_ref, mu_ref, f_ref, ld_ref, logw_ref, act_ref,
     def _assign():
         # step (e): mirror of kernels/assign._assign_gauss_kernel on one
         # streamed (bk, d, d) Cholesky tile
-        diff = x[:, None, :] - mu_ref[...][None, :, :]   # (bn, bk, d)
-        y = jax.lax.dot_general(
-            diff.transpose(1, 0, 2), f_ref[...],
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)          # (bk, bn, d)
-        maha = jnp.sum(y * y, axis=-1)                   # (bk, bn)
-        ll = (0.5 * (ld_ref[...][:, None] - maha) - 0.5 * d * LOG_2PI).T
-        t = ll + logw_ref[...][None, :]
-        t = jnp.where(act_ref[...][None, :] != 0, t, NEG_INF)
-        cid = jnp.broadcast_to(slot_ref[...][None, :], t.shape)
-        t = t + prng.gumbel(kz_ref[...], gidx[:, None], cid)
+        ll = gauss_block_ll(x, mu_ref[...], f_ref[...], ld_ref[...], d)
+        t = _noisy_logits(ll, logw_ref[...], act_ref[...], slot_ref[...],
+                          kz_ref, gidx)
         _fold_best(j, bk, t, best_ref, lab_ref)
 
     @pl.when(p == 1)
     def _sub_and_stats():
-        # step (f): mirror of kernels/assign._sub_assign_gauss_kernel,
-        # gathering from the owning K-block only (clipped local label;
-        # off-block rows gather garbage that the in_blk mask discards)
-        lab = lab_ref[...]
-        loc = lab - j * bk
+        # step (f): mirror of kernels/assign._sub_assign_gauss_kernel — the
+        # block's 2bk sub-cluster likelihoods on the MXU, each point
+        # keeping its own cluster's pair
+        loc = lab_ref[...] - j * bk
         in_blk = (loc >= 0) & (loc < bk)
-        locc = jnp.clip(loc, 0, bk - 1)
-        mu_own = jnp.take(smu_ref[...], locc, axis=0)    # (bn, 2, d)
-        f_own = jnp.take(sfchol_ref[...], locc, axis=0)  # (bn, 2, d, d)
-        ld_own = jnp.take(sld_ref[...], locc, axis=0)    # (bn, 2)
-        logw_own = jnp.take(sublogw_ref[...], locc, axis=0)
-        diff2 = x[:, None, :] - mu_own
-        y2 = jnp.einsum("nsd,nsde->nse", diff2, f_own,
-                        preferred_element_type=jnp.float32)
-        maha2 = jnp.sum(y2 * y2, axis=-1)
-        ll2 = 0.5 * (ld_own - maha2) - 0.5 * d * LOG_2PI
-        t2 = ll2 + logw_own
-        cid2 = jax.lax.broadcasted_iota(jnp.uint32, t2.shape, 1)
-        t2 = t2 + prng.gumbel(kzb_ref[...], gidx[:, None], cid2)
-        sub = jnp.argmax(t2, axis=1).astype(jnp.int32)
+        ll2 = gauss_block_ll(x, smu_ref[...], sfchol_ref[...], sld_ref[...],
+                             d)
+        sub = own_sub_labels(ll2, sublogw_ref[...], loc, kzb_ref, gidx)
         sub = jnp.where(in_blk, sub, sub_ref[...])
         sub_ref[...] = sub
 
         # stat fold: mirror of kernels/suffstats._suffstats_labels_kernel
         # restricted to this K-block's 2*bk segments
         r = _seg_onehot_block(loc, sub, valid_ref[...], n_ref.shape[1])
-        n_ref[...] = jnp.sum(r, axis=0)[None, :]
-        sx_ref[...] = jnp.dot(r.T, x,
-                              preferred_element_type=jnp.float32)[None]
+        n_ref[...] = jnp.sum(r, axis=0, keepdims=True)
+        sx_ref[...] = jnp.dot(r.T, x, preferred_element_type=jnp.float32)
         xw = r.T[:, :, None] * x[None, :, :]             # (2bk, bn, d)
         sxx_ref[...] = jax.lax.dot_general(
             xw.transpose(0, 2, 1),
             jnp.broadcast_to(x, (r.shape[1],) + x.shape),
             dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)[None]
+            preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bk", "interpret"))
@@ -366,66 +339,50 @@ def sweep_gauss(x: jax.Array, mu: jax.Array, chol_prec: jax.Array,
         slots = jnp.arange(k, dtype=jnp.uint32)
     bk = min(bk, k) or 1
     x, valid, gidx = _pad_points(
-        (x, jnp.asarray(valid, jnp.float32), gidx.astype(jnp.uint32)), bn)
+        (x, jnp.asarray(valid, jnp.float32)[:, None],
+         gidx.astype(jnp.uint32)[:, None]), bn)
     pk = (-k) % bk
-    mu = _pad_dim(mu, 0, pk)
-    chol_prec = _pad_dim(chol_prec, 0, pk)
-    logdet_prec = _pad_dim(logdet_prec, 0, pk)
-    logw = _pad_dim(logw, 0, pk)
-    active = _pad_dim(active.astype(jnp.int32), 0, pk)
-    slots = _pad_dim(slots.astype(jnp.uint32), 0, pk)
-    sub_mu = _pad_dim(sub_mu, 0, pk)
-    sub_chol_prec = _pad_dim(sub_chol_prec, 0, pk)
-    sub_logdet_prec = _pad_dim(sub_logdet_prec, 0, pk)
-    sublogw = _pad_dim(sublogw, 0, pk)
-    k_pad = mu.shape[0]
-    s = 2 * k_pad
+    k_pad = k + pk
     sb = 2 * bk
     gn = x.shape[0] // bn
     gk = k_pad // bk
     spb = STATS_BLOCK // bn
     nsb = -(-gn // spb)
+    blk = lambda i, p, j: j
+    point_in, point_out = _sweep_specs(bn)
 
     _, labels, sublabels, n2, sx2, sxx2 = pl.pallas_call(
         _sweep_gauss_kernel,
         grid=(gn, 2, gk),
         in_specs=[
             pl.BlockSpec((bn, d), lambda i, p, j: (i, 0)),
-            pl.BlockSpec((bk, d), lambda i, p, j: (j, 0)),
+            pl.BlockSpec((bk, 1, d), lambda i, p, j: (j, 0, 0)),
             pl.BlockSpec((bk, d, d), lambda i, p, j: (j, 0, 0)),
-            pl.BlockSpec((bk,), lambda i, p, j: (j,)),
-            pl.BlockSpec((bk,), lambda i, p, j: (j,)),
-            pl.BlockSpec((bk,), lambda i, p, j: (j,)),
-            pl.BlockSpec((bk,), lambda i, p, j: (j,)),
-            pl.BlockSpec((bk, 2, d), lambda i, p, j: (j, 0, 0)),
-            pl.BlockSpec((bk, 2, d, d), lambda i, p, j: (j, 0, 0, 0)),
-            pl.BlockSpec((bk, 2), lambda i, p, j: (j, 0)),
-            pl.BlockSpec((bk, 2), lambda i, p, j: (j, 0)),
-            pl.BlockSpec((bn,), lambda i, p, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, p, j: (i,)),
-            pl.BlockSpec((2,), lambda i, p, j: (0,)),
-            pl.BlockSpec((2,), lambda i, p, j: (0,)),
+            col_spec(bk, blk), row_spec(bk, blk), row_spec(bk, blk),
+            row_spec(bk, blk),
+            pl.BlockSpec((sb, 1, d), lambda i, p, j: (j, 0, 0)),
+            pl.BlockSpec((sb, d, d), lambda i, p, j: (j, 0, 0)),
+            col_spec(sb, blk), row_spec(sb, blk),
+            *point_in,
         ],
-        out_specs=[
-            pl.BlockSpec((bn,), lambda i, p, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, p, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, p, j: (i,)),
-            pl.BlockSpec((1, sb), lambda i, p, j: (i, j * p)),
-            pl.BlockSpec((1, sb, d), lambda i, p, j: (i, j * p, 0)),
-            pl.BlockSpec((1, sb, d, d), lambda i, p, j: (i, j * p, 0, 0)),
-        ],
+        out_specs=[*point_out, _n_spec(sb), _stat_spec(sb, d),
+                   _stat_spec(sb, d, d)],
         out_shape=[
-            jax.ShapeDtypeStruct((x.shape[0],), jnp.float32),
-            jax.ShapeDtypeStruct((x.shape[0],), jnp.int32),
-            jax.ShapeDtypeStruct((x.shape[0],), jnp.int32),
-            jax.ShapeDtypeStruct((gn, s), jnp.float32),
-            jax.ShapeDtypeStruct((gn, s, d), jnp.float32),
-            jax.ShapeDtypeStruct((gn, s, d, d), jnp.float32),
+            *_label_shapes(x.shape[0]),
+            jax.ShapeDtypeStruct((gn, gk, 1, sb), jnp.float32),
+            jax.ShapeDtypeStruct((gn, 2 * k_pad, d), jnp.float32),
+            jax.ShapeDtypeStruct((gn, 2 * k_pad, d, d), jnp.float32),
         ],
         interpret=interpret,
-    )(x, mu, chol_prec, logdet_prec, logw, active, slots, sub_mu,
-      sub_chol_prec, sub_logdet_prec, sublogw, valid, gidx, key_z, key_zb)
-    n2 = _fold_stats(n2, spb).reshape(nsb, k_pad, 2)[:, :k]
+    )(x, _pad_dim(mu, 0, pk)[:, None, :], _pad_dim(chol_prec, 0, pk),
+      _cols(_pad_dim(logdet_prec, 0, pk), bk),
+      *_cluster_vectors(bk, pk, logw, active, slots),
+      _pad_dim(sub_mu, 0, pk).reshape(-1, 1, d),
+      _pad_dim(sub_chol_prec, 0, pk).reshape(-1, d, d),
+      _cols(_pad_dim(sub_logdet_prec, 0, pk).reshape(-1), sb),
+      _rows(_pad_dim(sublogw, 0, pk).reshape(-1), sb),
+      valid, gidx, key_z, key_zb)
+    n2 = _fold_stats(n2.reshape(gn, -1), spb).reshape(nsb, k_pad, 2)[:, :k]
     sx2 = _fold_stats(sx2, spb).reshape(nsb, k_pad, 2, d)[:, :k]
     sxx2 = _fold_stats(sxx2, spb).reshape(nsb, k_pad, 2, d, d)[:, :k]
-    return labels[:n], sublabels[:n], n2, sx2, sxx2
+    return labels[:n, 0], sublabels[:n, 0], n2, sx2, sxx2

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import DPMMConfig
-from repro.core.distributed import shard_map
 from repro.core.family import get_family, state_partition_specs
 from repro.core.sampler import dpmm_step
 from repro.core.state import ModelState, PointState
@@ -98,10 +97,10 @@ def main(argv=None):
         valid=jax.ShapeDtypeStruct((n,), f32))
     xs = jax.ShapeDtypeStruct((n, d), f32)
 
-    step = jax.jit(shard_map(
+    step = jax.jit(jax.shard_map(
         functools.partial(dpmm_step, **kwargs), mesh=mesh,
         in_specs=(*state_specs, x_spec),
-        out_specs=state_specs))
+        out_specs=state_specs, check_vma=False))
     with mesh:
         lowered = step.lower(model, point, xs)
         compiled = lowered.compile()

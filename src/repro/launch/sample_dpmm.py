@@ -22,6 +22,7 @@ from repro.configs import DPMMConfig
 from repro.core.family import available_families
 from repro.core.sampler import DPMM
 from repro.data.synthetic import generate_gmm, generate_mnmm, generate_pmm
+from repro.launch.entry import configure_compile_cache, require_chip_for_pallas
 
 # reference-CLI aliases on top of the registry's canonical names
 _PRIOR_ALIASES = {"gaussian": "gaussian", "multinomial": "multinomial",
@@ -94,6 +95,7 @@ def main(argv=None):
                          "--tile-size/--checkpoint-every/--resume")
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     overrides = {}
     if args.params_path:
@@ -115,6 +117,7 @@ def main(argv=None):
                  else overrides.get("workers")),
         seed=args.seed,
     )
+    require_chip_for_pallas(cfg.use_pallas)
     if (args.resume or args.checkpoint_every) and not args.checkpoint_path:
         raise SystemExit("--resume/--checkpoint-every need "
                          "--checkpoint-path (the rotation prefix)")

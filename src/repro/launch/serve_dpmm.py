@@ -66,7 +66,11 @@ def main(argv=None):
     ap.add_argument("--bench-reps", type=int, default=20)
     args = ap.parse_args(argv)
 
+    from repro.launch.entry import (configure_compile_cache,
+                                    require_chip_for_pallas)
     from repro.serve.dpmm import DPMMEngine, ServeConfig
+    configure_compile_cache()
+    require_chip_for_pallas(args.use_pallas)
 
     fields = {"use_pallas": args.use_pallas, "seed": args.seed}
     if args.batch_size is not None:

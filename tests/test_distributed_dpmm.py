@@ -52,7 +52,7 @@ def test_only_suffstats_cross_shards(data):
 
     # reproduce the fit()'s compiled step to inspect its HLO
     from repro.core.sampler import _init_local, dpmm_step
-    from repro.core.distributed import data_axes_of, shard_map, shard_points
+    from repro.core.distributed import data_axes_of, shard_points
     from repro.core.family import state_partition_specs
     from jax.sharding import PartitionSpec as P
 
@@ -64,15 +64,15 @@ def test_only_suffstats_cross_shards(data):
     shard_spec = P(axes)
     rep = P()
     state_specs = state_partition_specs(model.family, shard_spec)
-    init = jax.jit(shard_map(
+    init = jax.jit(jax.shard_map(
         functools.partial(_init_local, **kwargs),
         mesh=mesh, in_specs=(rep, shard_spec, shard_spec),
-        out_specs=state_specs))
+        out_specs=state_specs, check_vma=False))
     model_state, point_state = init(jax.random.key(0), xs, valid)
-    step = jax.jit(shard_map(
+    step = jax.jit(jax.shard_map(
         functools.partial(dpmm_step, **kwargs), mesh=mesh,
         in_specs=(*state_specs, shard_spec),
-        out_specs=state_specs))
+        out_specs=state_specs, check_vma=False))
     hlo = step.lower(model_state, point_state, xs).compile().as_text()
 
     n_local = x.shape[0] // jax.device_count()

@@ -15,10 +15,9 @@ order), regenerate and commit the goldens deliberately:
         --update-goldens
 
 Environment contract: fingerprints are taken on the pinned CI jax
-version with the conftest's 4 virtual CPU devices — that is the
-environment the golden job provides. The latest-stable matrix leg does
-NOT run this suite (XLA codegen may legitimately differ across
-versions).
+version (0.9.0) with the conftest's 4 virtual CPU devices — that is
+the environment the golden job provides. XLA codegen may legitimately
+differ across versions, so a JAX upgrade re-records them.
 """
 import hashlib
 import json

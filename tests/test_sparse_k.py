@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 
 from repro.configs import DPMMConfig
@@ -196,11 +197,18 @@ def _find_pallas_calls(jaxpr, out):
             out.append(eqn)
         for p in eqn.params.values():
             for q in (p if isinstance(p, (list, tuple)) else (p,)):
-                if isinstance(q, jax.core.ClosedJaxpr):
+                if isinstance(q, jex_core.ClosedJaxpr):
                     _find_pallas_calls(q.jaxpr, out)
-                elif isinstance(q, jax.core.Jaxpr):
+                elif isinstance(q, jex_core.Jaxpr):
                     _find_pallas_calls(q, out)
     return out
+
+
+def _block_dims(block_mapping):
+    """Integer extents of a block (``Blocked(n)`` entries; squeezed dims
+    have none)."""
+    return [getattr(d, "block_size", d) for d in block_mapping.block_shape
+            if isinstance(getattr(d, "block_size", d), int)]
 
 
 @pytest.mark.parametrize("name", ("gaussian", "multinomial"))
@@ -225,8 +233,9 @@ def test_megakernel_params_are_k_block_tiled(name):
     assert len(grid) == 3 and grid[1] == 2 and grid[2] == k_max // bk, (
         f"expected (gn, 2, {k_max // bk}) grid, got {grid}")
     for bm in gm.block_mappings:
-        dims = [d for d in bm.block_shape if isinstance(d, int)]
-        assert k_max not in dims, (
+        dims = _block_dims(bm)
+        assert dims, f"no block extents read from {bm.block_shape}"
+        assert k_max not in dims and 2 * k_max not in dims, (
             f"(k_max, ...)-resident block {bm.block_shape}: the kernel "
             "must stream K-blocks, not hold the full slab in VMEM")
 
