@@ -15,14 +15,17 @@ to the smallest covering step (serve/dpmm.py).
 
 The JSON written to ``--result-path`` is exactly
 ``ServeResult.to_json()`` — the CLI and the Python API emit the same
-schema, field for field. With ``--bench`` it instead reports
-steady-state throughput plus per-request latency percentiles through
-the ladder. Without ``--queries`` a synthetic batch matching the
-checkpoint's feature dim is drawn — a smoke mode for CI and demos.
+schema, field for field. With ``--profile-dir DIR`` the query runs
+under ``jax.profiler.trace(DIR)``: the trace holds the engine's
+``dpmm.serve.*`` spans (serve/dpmm.py) beside the device's operations,
+for TensorBoard or Perfetto. Without ``--queries`` a synthetic batch
+matching the checkpoint's feature dim is drawn — a smoke mode for CI
+and demos.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import time
 import warnings
@@ -60,11 +63,11 @@ def main(argv=None):
                     help="include the (N, K_max) soft assignment in the "
                          "result JSON")
     ap.add_argument("--result-path", "--result_path", default="")
-    ap.add_argument("--bench", action="store_true",
-                    help="measure throughput/latency instead of dumping "
-                         "answers")
-    ap.add_argument("--bench-reps", type=int, default=20)
+    ap.add_argument("--profile-dir", default="",
+                    help="write a profiler trace of the query here")
     args = ap.parse_args(argv)
+
+    import jax
 
     from repro.launch.entry import (configure_compile_cache,
                                     require_chip_for_pallas)
@@ -96,30 +99,18 @@ def main(argv=None):
         xq = rng.standard_normal((args.n, engine.d)).astype(np.float32)
         print(f"no --queries: serving {args.n} synthetic rows")
 
-    if args.bench:
-        engine.query(xq[: engine.batch_sizes[0]])    # warm (already AOT)
-        lat = []
+    profile = (jax.profiler.trace(args.profile_dir) if args.profile_dir
+               else contextlib.nullcontext())
+    with profile:
         t0 = time.perf_counter()
-        for _ in range(args.bench_reps):
-            t1 = time.perf_counter()
-            engine.query(xq)
-            lat.append(time.perf_counter() - t1)
-        dt = (time.perf_counter() - t0) / args.bench_reps
-        qps = xq.shape[0] / dt
-        p50, p95, p99 = (float(np.percentile(lat, p) * 1e3)
-                         for p in (50, 95, 99))
-        print(f"throughput: {qps:,.0f} queries/s "
-              f"({dt * 1e3:.2f} ms per {xq.shape[0]}-row request; "
-              f"p50={p50:.2f} p95={p95:.2f} p99={p99:.2f} ms)")
-        return
-
-    t0 = time.perf_counter()
-    res = engine.query(xq, sample=args.sample, seed=args.seed)
-    dt = time.perf_counter() - t0
+        res = engine.query(xq, sample=args.sample, seed=args.seed)
+        dt = time.perf_counter() - t0
     print(f"served {xq.shape[0]} queries in {dt * 1e3:.1f} ms "
           f"({xq.shape[0] / dt:,.0f} q/s): "
           f"{len(res.cluster_counts())} clusters hit, "
           f"mean log p(x) = {res.log_predictive.mean():.3f}")
+    if args.profile_dir:
+        print(f"wrote a profiler trace under {args.profile_dir}")
     if args.result_path:
         with open(args.result_path, "w") as f:
             json.dump(res.to_json(include_logprobs=args.include_logprobs),

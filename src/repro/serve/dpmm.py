@@ -76,6 +76,28 @@ and the (N, K_max) soft output is the compact one scattered into a
 ``NEG_INF`` background — float32 ``NEG_INF - logpred`` rounds to
 ``NEG_INF`` exactly, which is what the dense step computes for inactive
 slots.
+
+**Spans.** ``query`` and the step table mark their phases with
+``jax.profiler.TraceAnnotation``, so a profiler trace shows the engine's
+host work on the same clock as the device's operations; counts ride as
+the span's arguments. With no profiler running a span costs the
+annotation object alone (about a microsecond).
+
+ - ``dpmm.serve.query`` (``rows``, ``segments``): the whole request.
+ - ``dpmm.serve.validate``: the dtype cast and the finiteness scan.
+ - ``dpmm.serve.segment`` (``used``, ``batch``): one ladder step, rows
+   answered over rows dispatched.
+ - ``dpmm.serve.pad``: the host zero-pad of the segment to its step.
+ - ``dpmm.serve.dispatch`` (``bytes``): the compiled step's call, with
+   the upload of the padded rows.
+ - ``dpmm.serve.copy_back`` (``out``, ``bytes``): one output's copy to
+   the host, at its padded size. The first one also waits for the step:
+   a separate wait before the copies would cost a round trip to the
+   device on every segment.
+ - ``dpmm.serve.assemble``: the concatenation into the ``ServeResult``.
+ - ``dpmm.serve.compile`` (``kind`` ``q``/``s``, ``batch``): a step
+   compiled into the table (engine build, swap, publish; never inside a
+   query).
 """
 from __future__ import annotations
 
@@ -94,6 +116,8 @@ from repro.core import gibbs, resilience
 from repro.core.family import NEG_INF, ComponentFamily, get_family
 from repro.core.state import ModelState
 from repro.kernels import prng
+
+_span = jax.profiler.TraceAnnotation
 
 
 class InvalidQueryError(ValueError):
@@ -301,7 +325,8 @@ class _StepTable:
         with self._lock:
             hit = self._compiled.get(key)
             if hit is None:
-                hit = self._compiled[key] = build()
+                with _span("dpmm.serve.compile", kind=key[0], batch=key[3]):
+                    hit = self._compiled[key] = build()
             return hit
 
     @staticmethod
@@ -601,28 +626,40 @@ class DPMMEngine:
         """All answers for (N, d) queries through the AOT step table.
         N = 0 returns empty answers. ``sample=True`` additionally draws
         ``sampled_labels`` (see :meth:`sample`)."""
-        served = self._served              # ONE snapshot for the request
-        x = self._validated(x, served.d)
-        self._record_traffic(x)
-        outs: Dict[str, list] = {"labels": [], "logprobs": [],
-                                 "log_predictive": []}
-        for start, used, b in self.plan_route(x.shape[0]):
-            out = served.steps[b](self._pad(x[start:start + used], b,
-                                            served.d), *served.ops)
-            for k, v in out.items():
-                outs[k].append(np.asarray(jax.device_get(v))[:used])
-        empty = not outs["labels"]
-        return ServeResult(
-            labels=(np.zeros((0,), np.int32) if empty
-                    else np.concatenate(outs["labels"])),
-            logprobs=(np.zeros((0, served.k_max), np.float32) if empty
-                      else np.concatenate(outs["logprobs"])),
-            log_predictive=(np.zeros((0,), np.float32) if empty
-                            else np.concatenate(outs["log_predictive"])),
-            sampled_labels=(self._sample(served, x, seed) if sample
-                            else None),
-            family=served.family.name, k_max=served.k_max,
-            model_epoch=served.epoch)
+        with _span("dpmm.serve.query") as request:
+            served = self._served          # ONE snapshot for the request
+            with _span("dpmm.serve.validate"):
+                x = self._validated(x, served.d)
+            self._record_traffic(x)
+            route = self.plan_route(x.shape[0])
+            request.set_metadata(rows=x.shape[0], segments=len(route))
+            outs: Dict[str, list] = {"labels": [], "logprobs": [],
+                                     "log_predictive": []}
+            for start, used, b in route:
+                with _span("dpmm.serve.segment", used=used, batch=b):
+                    with _span("dpmm.serve.pad"):
+                        block = self._pad(x[start:start + used], b, served.d)
+                    with _span("dpmm.serve.dispatch", bytes=block.nbytes):
+                        out = served.steps[b](block, *served.ops)
+                    for k, v in out.items():
+                        with _span("dpmm.serve.copy_back", out=k,
+                                   bytes=v.nbytes):
+                            outs[k].append(
+                                np.asarray(jax.device_get(v))[:used])
+            sampled = self._sample(served, x, seed) if sample else None
+            with _span("dpmm.serve.assemble"):
+                empty = not outs["labels"]
+                return ServeResult(
+                    labels=(np.zeros((0,), np.int32) if empty
+                            else np.concatenate(outs["labels"])),
+                    logprobs=(np.zeros((0, served.k_max), np.float32)
+                              if empty else np.concatenate(outs["logprobs"])),
+                    log_predictive=(
+                        np.zeros((0,), np.float32) if empty
+                        else np.concatenate(outs["log_predictive"])),
+                    sampled_labels=sampled,
+                    family=served.family.name, k_max=served.k_max,
+                    model_epoch=served.epoch)
 
     def predict(self, x: np.ndarray) -> np.ndarray:
         return self.query(x).labels

@@ -172,3 +172,34 @@ def test_serve_cli_roundtrip(fitted, tmp_path, capsys):
     engine = DPMMEngine(result.state, "gaussian", ServeConfig(batch_sizes=(128,)))
     assert np.array_equal(np.asarray(payload["labels"], np.int32),
                           engine.predict(xq[:200]))
+
+
+def test_serve_cli_profile_dir_traces_the_engine_spans(fitted, tmp_path):
+    """``--profile-dir`` writes a profiler trace whose host plane holds
+    the engine's request span, with its row count."""
+    import glob
+    import warnings
+
+    from jax.profiler import ProfileData
+
+    from repro.launch import serve_dpmm
+
+    result, xq, _ = fitted
+    ckpt = str(tmp_path / "cli.npz")
+    save_model(ckpt, result.state, "gaussian")
+    qpath = str(tmp_path / "q.npy")
+    np.save(qpath, xq[:200])
+    serve_dpmm.main(["--checkpoint", ckpt, "--queries", qpath,
+                     "--batch-sizes", "128",
+                     "--profile-dir", str(tmp_path / "trace")])
+    paths = glob.glob(str(tmp_path / "trace" / "**" / "*.xplane.pb"),
+                      recursive=True)
+    assert len(paths) == 1
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", DeprecationWarning)
+        queries = [dict(e.stats)
+                   for plane in ProfileData.from_file(paths[0]).planes
+                   if plane.name.startswith("/host:")
+                   for line in plane.lines for e in line.events
+                   if e.name == "dpmm.serve.query"]
+    assert queries == [{"rows": 200, "segments": 2}]
