@@ -86,14 +86,19 @@ annotation object alone (about a microsecond).
  - ``dpmm.serve.query`` (``rows``, ``segments``): the whole request.
  - ``dpmm.serve.validate``: the dtype cast and the finiteness scan.
  - ``dpmm.serve.segment`` (``used``, ``batch``): one ladder step, rows
-   answered over rows dispatched.
+   answered over rows dispatched; its own time includes starting the
+   host copies of the step's outputs.
  - ``dpmm.serve.pad``: the host zero-pad of the segment to its step.
  - ``dpmm.serve.dispatch`` (``bytes``): the compiled step's call, with
    the upload of the padded rows.
- - ``dpmm.serve.copy_back`` (``out``, ``bytes``): one output's copy to
-   the host, at its padded size. The first one also waits for the step:
-   a separate wait before the copies would cost a round trip to the
-   device on every segment.
+ - ``dpmm.serve.copy_back`` (``out``, ``bytes``, ``inflight``): the
+   read of one output's copy to the host, at its padded size, after
+   every segment has been dispatched and every copy started.
+   ``inflight`` counts the request's copies started and not yet read
+   when this read begins: all of them on the first read, 1 on the last.
+   The first read holds the wait for the steps and the transfers; the
+   later reads find their data on the host or nearly so. A separate
+   wait before the copies would cost a round trip to the device.
  - ``dpmm.serve.assemble``: the concatenation into the ``ServeResult``.
  - ``dpmm.serve.compile`` (``kind`` ``q``/``s``, ``batch``): a step
    compiled into the table (engine build, swap, publish; never inside a
@@ -633,8 +638,11 @@ class DPMMEngine:
             self._record_traffic(x)
             route = self.plan_route(x.shape[0])
             request.set_metadata(rows=x.shape[0], segments=len(route))
-            outs: Dict[str, list] = {"labels": [], "logprobs": [],
-                                     "log_predictive": []}
+            # every output's copy to the host starts as soon as its step
+            # is dispatched, and none is read before all have started:
+            # each blocking read costs a round trip to the device, so
+            # the request pays one wait, not one per output and segment
+            started: List[Tuple[str, jax.Array, int]] = []
             for start, used, b in route:
                 with _span("dpmm.serve.segment", used=used, batch=b):
                     with _span("dpmm.serve.pad"):
@@ -642,10 +650,14 @@ class DPMMEngine:
                     with _span("dpmm.serve.dispatch", bytes=block.nbytes):
                         out = served.steps[b](block, *served.ops)
                     for k, v in out.items():
-                        with _span("dpmm.serve.copy_back", out=k,
-                                   bytes=v.nbytes):
-                            outs[k].append(
-                                np.asarray(jax.device_get(v))[:used])
+                        v.copy_to_host_async()
+                        started.append((k, v, used))
+            outs: Dict[str, list] = {"labels": [], "logprobs": [],
+                                     "log_predictive": []}
+            for i, (k, v, used) in enumerate(started):
+                with _span("dpmm.serve.copy_back", out=k, bytes=v.nbytes,
+                           inflight=len(started) - i):
+                    outs[k].append(np.asarray(v)[:used])
             sampled = self._sample(served, x, seed) if sample else None
             with _span("dpmm.serve.assemble"):
                 empty = not outs["labels"]

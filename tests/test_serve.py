@@ -177,11 +177,6 @@ def test_serve_cli_roundtrip(fitted, tmp_path, capsys):
 def test_serve_cli_profile_dir_traces_the_engine_spans(fitted, tmp_path):
     """``--profile-dir`` writes a profiler trace whose host plane holds
     the engine's request span, with its row count."""
-    import glob
-    import warnings
-
-    from jax.profiler import ProfileData
-
     from repro.launch import serve_dpmm
 
     result, xq, _ = fitted
@@ -192,14 +187,55 @@ def test_serve_cli_profile_dir_traces_the_engine_spans(fitted, tmp_path):
     serve_dpmm.main(["--checkpoint", ckpt, "--queries", qpath,
                      "--batch-sizes", "128",
                      "--profile-dir", str(tmp_path / "trace")])
-    paths = glob.glob(str(tmp_path / "trace" / "**" / "*.xplane.pb"),
-                      recursive=True)
+    assert _host_spans(tmp_path / "trace", "dpmm.serve.query") == [
+        {"rows": 200, "segments": 2}]
+
+
+def _host_spans(trace_dir, name):
+    """The arguments of the ``name`` spans on the host plane of the one
+    profiler trace under ``trace_dir``, in the order they started."""
+    import glob
+    import warnings
+
+    from jax.profiler import ProfileData
+
+    paths = glob.glob(str(trace_dir / "**" / "*.xplane.pb"), recursive=True)
     assert len(paths) == 1
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", DeprecationWarning)
-        queries = [dict(e.stats)
-                   for plane in ProfileData.from_file(paths[0]).planes
-                   if plane.name.startswith("/host:")
-                   for line in plane.lines for e in line.events
-                   if e.name == "dpmm.serve.query"]
-    assert queries == [{"rows": 200, "segments": 2}]
+        events = [(e.start_ns, dict(e.stats))
+                  for plane in ProfileData.from_file(paths[0]).planes
+                  if plane.name.startswith("/host:")
+                  for line in plane.lines for e in line.events
+                  if e.name == name]
+    return [stats for _, stats in sorted(events, key=lambda e: e[0])]
+
+
+@pytest.mark.parametrize("n", [1, 127, 128, 129, 300])
+def test_query_starts_every_copy_before_the_first_read(fitted, tmp_path, n):
+    """Every output's copy to the host starts before any is read: the
+    first ``copy_back`` span counts all of the request's copies in
+    flight (three outputs a segment), the last one. The answers are
+    bitwise those of the same steps read one output at a time."""
+    result, xq, _ = fitted
+    engine = DPMMEngine(result.state, "gaussian",
+                        ServeConfig(batch_sizes=(128,)))
+    x = xq[:n]
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        got = engine.query(x)
+    route = engine.plan_route(n)
+    served = engine._served
+    one_at_a_time = {"labels": [], "logprobs": [], "log_predictive": []}
+    for start, used, b in route:
+        out = served.steps[b](engine._pad(x[start:start + used], b, D),
+                              *served.ops)
+        for k, v in out.items():
+            one_at_a_time[k].append(np.asarray(jax.device_get(v))[:used])
+    copies = _host_spans(tmp_path / "trace", "dpmm.serve.copy_back")
+    assert [c["inflight"] for c in copies] == list(
+        range(3 * len(route), 0, -1))
+    assert [c["out"] for c in copies] == list(out) * len(route)
+    for k, parts in one_at_a_time.items():
+        want = np.concatenate(parts)
+        assert getattr(got, k).dtype == want.dtype
+        assert np.array_equal(getattr(got, k), want), k
