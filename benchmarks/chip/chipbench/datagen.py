@@ -4,12 +4,21 @@ A copy of the program's generators (``repro.data.synthetic``), kept here so
 that the benchmark's yardstick does not move when the program changes.
 ``generate`` is the one entry point: a configuration's ``data`` block names
 the generator and its parameters.
+
+A data law beyond ``GENERATORS`` is a file of its own,
+``generators/<name>.py``, found by the name in ``data["generator"]``. It
+defines ``generate(n, d, k, seed, **params)``, returning ``x`` float32
+(n, d) and the true labels int32 (n,), the same for the same seed; and,
+where a ``served/<family>.py`` builds its model from the law, may define
+``mixture(d, k, seed, **params)``, the law's parameters for that seed.
 """
 from __future__ import annotations
 
 from typing import Tuple
 
 import numpy as np
+
+from chipbench import spec
 
 
 def _gmm_mixture(rng: np.random.Generator, d: int, k: int, sep: float):
@@ -71,6 +80,20 @@ GENERATORS = {"gmm": generate_gmm, "mnmm": generate_mnmm}
 
 def generate(data: dict, seed: int) -> Tuple[np.ndarray, np.ndarray]:
     """(x, true labels) for a configuration's ``data`` block, e.g.
-    ``{"generator": "gmm", "n": 1000000, "d": 32, "k": 16, "sep": 6.0}``."""
+    ``{"generator": "gmm", "n": 1000000, "d": 32, "k": 16, "sep": 6.0}``:
+    a generator of ``GENERATORS``, else ``generators/<name>.py`` under
+    the benchmark's directory (``spec.HERE``)."""
+    name = data["generator"]
     params = {key: v for key, v in data.items() if key != "generator"}
-    return GENERATORS[data["generator"]](seed=seed, **params)
+    if name in GENERATORS:
+        return GENERATORS[name](seed=seed, **params)
+    law = spec.load_module("generators", name, spec.HERE)
+    x, labels = law.generate(seed=seed, **params)
+    n, d = int(data["n"]), int(data["d"])
+    if (x.dtype != np.float32 or x.shape != (n, d)
+            or labels.dtype != np.int32 or labels.shape != (n,)):
+        raise ValueError(f"generator {name!r} returned x {x.dtype} "
+                         f"{x.shape} and labels {labels.dtype} "
+                         f"{labels.shape}; the contract is float32 "
+                         f"({n}, {d}) and int32 ({n},)")
+    return x, labels

@@ -32,7 +32,7 @@ from typing import Callable, Dict, Optional
 
 import numpy as np
 
-from chipbench import check, datagen, spec, tracereduce
+from chipbench import check, datagen, programspans, spec, tracereduce
 
 KIND = "serve_open_loop"
 SPIN_S = 1e-3              # the last stretch of a wait spins: a sleeping
@@ -208,25 +208,46 @@ def control_answers(lp_low: np.ndarray, served: dict):
     return labels, logpred.astype(np.float64)
 
 
-def trace_context(cell: dict, events: dict, loop: dict, sched: dict,
-                  served: dict, device_kind: str, compile_spans) -> dict:
+def engine_context(events: dict, program: list, loop: dict,
+                   compile_spans) -> dict:
+    """The traced serving window reduced: the device's busy time
+    (``trace``) on the ``chips`` the engine used, less compilation; every
+    ``dpmm.*`` span starting in the window (``program``); and the serving
+    engine's readings from its request spans (``engine``, None where
+    there is none)."""
     window = tracereduce.host_window(events, "bench.serve_window")
     if window is None:
         raise RuntimeError("the trace holds no serving window span")
     # the engine serves from one chip; a chip it never touched is not
     # part of its busy time
     used = {plane: ops for plane, ops in events["devices"].items() if ops}
-    events = dict(events, devices=used)
     offset = window[0] - loop["wall_start"] * 1e9
     exclude = [(s * 1e9 + offset, e * 1e9 + offset)
                for s, e in compile_spans]
+    trace = tracereduce.reduce_events(dict(events, devices=used), window,
+                                      exclude)
+    requests = int(np.sum(np.isfinite(loop["latency_s"])))
+    in_requests = programspans.reduce_program(program, window,
+                                              programspans.REQUEST_SPAN)
+    idle = programspans.engine_idle(used, program, window, exclude)
+    return {"trace": trace, "chips": len(used),
+            "program": programspans.reduce_program(program, window),
+            "engine": programspans.engine_readings(
+                in_requests, idle, requests, trace["window_s"])}
+
+
+def trace_context(cell: dict, events: dict, program: list, loop: dict,
+                  sched: dict, served: dict, device_kind: str,
+                  compile_spans) -> dict:
+    """The per-layer readers' input: ``engine_context`` and the work of
+    the rows answered, with the chip's peaks."""
     counts = spec.load_module("counts", cell["config"]["family"])
     rows = int(np.sum(sched["rows"][np.isfinite(loop["latency_s"])]))
-    return {"trace": tracereduce.reduce_events(events, window, exclude),
-            "query_work": counts.query_work(rows, served["d"],
-                                            len(served["slots"]),
-                                            served["k_max"]),
-            "peaks": spec.peaks(device_kind), "chips": len(used)}
+    return dict(engine_context(events, program, loop, compile_spans),
+                query_work=counts.query_work(rows, served["d"],
+                                             len(served["slots"]),
+                                             served["k_max"]),
+                peaks=spec.peaks(device_kind))
 
 
 def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devices,
@@ -270,7 +291,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devices,
         context = None
         if trace:
             context = trace_context(
-                cell, tracereduce.load_events(trace_dir), loop, sched,
+                cell, tracereduce.load_events(trace_dir),
+                programspans.load_program(trace_dir), loop, sched,
                 prep["served"], devices[0].device_kind, compile_spans)
     finally:
         if trace_dir:

@@ -4,8 +4,20 @@
 a configuration (its file is in the ``configs`` entry) and a traffic mix
 (``mixes/<traffic>.json``). A per-layer metric ``m`` is read by
 ``metrics/<m>.py``; a family ``f``'s work per iteration is
-``counts/<f>.py`` and its plain reference ``reference/<f>.py``. Adding any
+``counts/<f>.py``, its plain reference ``reference/<f>.py`` and its
+served model ``served/<f>.py``; a data law ``g`` other than the built-in
+``gmm`` and ``mnmm`` is ``generators/<g>.py``, defining
+``generate(n, d, k, seed, **params) -> (x float32 (n, d), labels int32
+(n,))`` and, for a served model to build from, optionally
+``mixture(d, k, seed, **params)`` (``chipbench/datagen.py``). Adding any
 of them is adding a file and an entry: nothing here changes.
+
+A metric reader's ``read(ctx)`` gets the traced run's context: ``trace``
+(the device's busy time and breakdown), ``chips``, ``peaks`` and the
+counts of the work, and ``program``, every ``dpmm.*`` span of the
+program in the window by name (``programspans.reduce_program``); a
+serving run's also ``engine``, the serving engine's readings
+(``programspans.engine_readings``).
 """
 from __future__ import annotations
 

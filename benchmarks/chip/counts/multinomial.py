@@ -14,3 +14,14 @@ def work(n: int, d: int, k_active: float) -> dict:
     flops = n * ((k_active + 2) * 2 * d + 2 * (d + 1))
     bytes_ = 2 * n * d * 4 + 3 * 2 * n * 4
     return {"flops": float(flops), "bytes": float(bytes_)}
+
+
+def query_work(rows: int, d: int, k_active: int, k_max: int) -> dict:
+    """Work the assignment engine needs to answer ``rows`` query rows:
+    each row's dot product with log theta under the K_active served
+    clusters (2 d each) and their log-sum-exp (3 each); bytes: the rows
+    read, and each row's label (int32), log predictive density and K_max
+    log posteriors (float32) written."""
+    flops = rows * k_active * (2 * d + 3)
+    bytes_ = rows * (d * 4 + 4 + 4 + k_max * 4)
+    return {"flops": float(flops), "bytes": float(bytes_)}

@@ -52,7 +52,8 @@ sys.path.insert(0, str(ROOT / "src"))
 
 import numpy as np             # noqa: E402
 
-from chipbench import check, datagen, serve, spec, tracereduce  # noqa: E402
+from chipbench import (check, datagen, programspans, serve, spec,  # noqa: E402
+                       tracereduce)
 
 FIT_KIND = "fit_continuation"
 
@@ -156,7 +157,8 @@ def peak_bytes(devices) -> int:
 
 
 def trace_context(cell, trace_dir, win, iters, k_mean, device_kind):
-    """The per-layer readers' input: the reduced trace and the counts."""
+    """The per-layer readers' input: the reduced trace, the program's
+    ``dpmm.*`` spans in the window by name, and the counts."""
     events = tracereduce.load_events(trace_dir)
     dispatch = tracereduce.host_window(events, "bench.chunk_dispatch")
     fit = tracereduce.host_window(events, "bench.window_fit")
@@ -171,6 +173,8 @@ def trace_context(cell, trace_dir, win, iters, k_mean, device_kind):
     data = cell["config"]["data"]
     counts = spec.load_module("counts", cell["config"]["family"])
     return {"trace": tracereduce.reduce_events(events, window, exclude),
+            "program": programspans.reduce_program(
+                programspans.load_program(trace_dir), window),
             "work": counts.work(data["n"], data["d"], k_mean),
             "peaks": spec.peaks(device_kind), "chips": cell["chips"],
             "iters_traced": iters}

@@ -9,13 +9,14 @@ For each seed, in one process: the cell's set-up, a window of
 ``--seconds`` of its open loop with no profiler (the latency the cell
 reports, and the engine's mean time per query on the host clock,
 ``engine_ms``), then a window of the mix's ``trace_seconds`` under
-``jax.profiler.trace``, timed the same way. The traced window is reduced twice: as the
-cell's traced run reduces it (the device's idle share,
-``tracereduce.reduce_events``) and by the engine's ``dpmm.*`` spans
-(``chipbench/programspans.py``): ``serve_engine_ms`` with each span's
-self time per request, ``serve_copy_back_ms``, ``engine_idle_pct`` and
-``serve_pad_efficiency``, and every span's count, times and argument
-sums. One JSON line per seed. ``--chips`` asks for fewer chips than the
+``jax.profiler.trace``, timed the same way. The traced window is reduced
+as the cell's traced run reduces it (``serve.engine_context``): the
+device's idle share, the engine's readings from its ``dpmm.*`` spans
+(``serve_engine_ms`` with each span's self time per request,
+``serve_copy_back_ms``, ``engine_idle_pct`` and
+``serve_pad_efficiency``), and every span's count, times and argument
+sums; ``outside_requests`` counts the spans that no request span holds.
+One JSON line per seed. ``--chips`` asks for fewer chips than the
 cell has (the engine serves from the first). The benchmark's own runs
 never run this.
 """
@@ -61,7 +62,8 @@ def timed_loop(prep: dict, sched: dict) -> tuple:
 
 
 def traced(cell: dict, prep: dict) -> dict:
-    """The mix's traced window, reduced both ways."""
+    """The mix's traced window, reduced as the cell's traced run reduces
+    it."""
     import jax
     from chipbench.window import WindowRecorder
     sched = serve.schedule(cell["mix"], float(cell["mix"]["trace_seconds"]),
@@ -77,27 +79,15 @@ def traced(cell: dict, prep: dict) -> dict:
         program = programspans.load_program(trace_dir)
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
-    # the window, chips and compilation of the cell's traced run
-    # (serve.trace_context)
-    window = tracereduce.host_window(events, "bench.serve_window")
-    used = {p: ops for p, ops in events["devices"].items() if ops}
-    offset = window[0] - loop["wall_start"] * 1e9
-    exclude = [(s * 1e9 + offset, e * 1e9 + offset)
-               for s, e in compile_spans]
-    device = tracereduce.reduce_events(dict(events, devices=used), window,
-                                       exclude)
-    spans = programspans.reduce_program(program, window,
-                                        programspans.REQUEST_SPAN)
-    idle = programspans.engine_idle(used, program, window, exclude)
-    requests = int(np.sum(np.isfinite(loop["latency_s"])))
+    ctx = serve.engine_context(events, program, loop, compile_spans)
+    engine = ctx["engine"]
+    inside = engine["serve_engine_ms"]["self_ms"] if engine else {}
     return {"latency": host, "compiles_in_window": n_comp,
-            "device_idle_pct.serve": device["idle_pct"],
-            "readings": programspans.engine_readings(
-                spans, idle, requests, device["window_s"]),
-            "spans": spans, "outside_requests": {
-                name: r["count"] for name, r in
-                programspans.reduce_program(program, window).items()
-                if name not in spans}}
+            "device_idle_pct.serve": ctx["trace"]["idle_pct"],
+            "readings": engine, "spans": ctx["program"],
+            "outside_requests": {name: r["count"]
+                                 for name, r in ctx["program"].items()
+                                 if name not in inside}}
 
 
 def main(argv=None) -> int:

@@ -74,3 +74,24 @@ def test_query_roofline_at_the_cells_sizes():
     assert got["bound"] == "bytes"
     assert 0.0 < got["value"] < 100.0
     assert reader({}) is None
+
+
+def test_multinomial_query_counts_by_hand():
+    # 10 rows, d=4, 3 served clusters: a dot product with log theta is
+    # 2*4 = 8 FLOP and the log-sum-exp 3 more per cluster; each row reads
+    # 4 floats and writes a label, a log predictive density and 5 log
+    # posteriors (K_max = 5).
+    work = spec.load_module("counts", "multinomial").query_work(10, 4, 3, 5)
+    assert work["flops"] == 10 * 3 * 11
+    assert work["bytes"] == 10 * (4 * 4 + 4 + 4 + 5 * 4)
+
+
+@pytest.mark.parametrize("family", ["gaussian", "multinomial"])
+def test_query_counts_follow_the_sizes_only(family):
+    query_work = spec.load_module("counts", family).query_work
+    one, two = query_work(1000, 8, 4, 64), query_work(2000, 8, 4, 64)
+    assert two["flops"] == 2 * one["flops"]
+    assert two["bytes"] == 2 * one["bytes"]
+    assert query_work(1000, 8, 8, 64)["flops"] == 2 * one["flops"]
+    assert query_work(1000, 8, 8, 64)["bytes"] == one["bytes"]
+    assert query_work(1000, 8, 4, 128)["bytes"] > one["bytes"]

@@ -1,7 +1,7 @@
 """The serving engine's own spans: their reduction on hand-made spans
 whose answers are counted by hand, their reading from a recorded trace,
-and the engine's spans on the CPU, where every count is known from the
-requests sent."""
+the engine's spans on the CPU, where every count is known from the
+requests sent, and their way into the cells' per-layer readers."""
 import contextlib
 import sys
 import tempfile
@@ -15,7 +15,8 @@ HERE = Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE.parent))
 sys.path.insert(0, str(HERE))
 
-from chipbench import programspans, serve, tracereduce  # noqa: E402
+import run  # noqa: E402
+from chipbench import programspans, serve, spec, tracereduce  # noqa: E402
 from chipbench_tiny import tiny_serve_cell  # noqa: E402
 
 SEED = 2 ** 33 + 5
@@ -254,3 +255,123 @@ def test_the_spans_tool_reads_a_serving_window(cell, prep):
         got["device_idle_pct.serve"] <= 100
     assert sum(read["engine_idle_pct"]["by_span"].values()) > 0
     assert got["outside_requests"] == {}
+
+
+ENGINE_METRICS = ["serve_engine_ms", "serve_copy_back_ms", "engine_idle_pct",
+                  "serve_pad_efficiency"]
+
+
+def _hand_context(program=HAND):
+    """``serve.trace_context`` over the hand-made spans and device
+    operations: the serving window is 0-600 ns on a clock that starts at
+    the loop's wall start, compilation 85-95 ns, two requests answered
+    and a third not, and a chip the engine never touched."""
+    events = {"devices": dict(DEVICES, **{"/device:TPU:2": []}),
+              "host": [["bench.serve_window", 0.0, 600.0],
+                       ["bench.wait", 100.0, 100.0]]}
+    loop = {"wall_start": 0.0,
+            "latency_s": np.array([1e-3, 2e-3, np.nan])}
+    sched = {"rows": np.array([10, 1, 3])}
+    served = {"d": 4, "slots": [0, 1, 2], "k_max": 8}
+    cell = {"config": {"family": "gaussian"}}
+    return serve.trace_context(cell, events, program, loop, sched, served,
+                               "TPU v5 lite", [(85e-9, 95e-9)])
+
+
+def test_the_cells_context_carries_the_programs_spans_and_the_engine():
+    ctx = _hand_context()
+    trace = tracereduce.reduce_events({"devices": DEVICES, "host": []},
+                                      WINDOW, EXCLUDE)
+    assert ctx["trace"]["busy_s"] == trace["busy_s"]
+    assert ctx["trace"]["window_s"] == trace["window_s"]
+    assert ctx["chips"] == 2
+    assert ctx["query_work"] == spec.load_module(
+        "counts", "gaussian").query_work(11, 4, 3, 8)
+    assert ctx["peaks"] == spec.peaks("TPU v5 lite")
+    assert ctx["program"] == programspans.reduce_program(HAND, WINDOW)
+    assert "dpmm.serve.compile" in ctx["program"]
+    assert ctx["engine"] == programspans.engine_readings(
+        programspans.reduce_program(HAND, WINDOW, programspans.REQUEST_SPAN),
+        programspans.engine_idle(DEVICES, HAND, WINDOW, EXCLUDE), 2,
+        trace["window_s"])
+
+
+@pytest.mark.parametrize("name", ENGINE_METRICS)
+def test_each_engine_reader_reads_its_number_or_nothing(name):
+    reader = spec.load_module("metrics", name).read
+    ctx = _hand_context()
+    assert reader(ctx) == ctx["engine"][name]
+    assert reader(ctx)["value"] > 0
+    no_request = [e for e in HAND if e[0] != programspans.REQUEST_SPAN]
+    bare = _hand_context(no_request)
+    assert bare["engine"] is None and bare["program"]
+    assert reader(bare) is None
+    assert reader({}) is None
+
+
+def test_a_traced_serving_run_reports_the_engines_readings(cell, prep):
+    """On the CPU, with a stand-in device plane and the v5e's peaks: the
+    traced run's line carries every per-layer metric of the cell, the
+    engine's four among them, and each reads as the context it was
+    given."""
+    import jax
+    cell = dict(cell, mix=dict(cell["mix"], trace_seconds=1.0))
+    peaks = spec.peaks("TPU v5 lite")
+    seen = {}
+    orig = serve.trace_context
+
+    def keep(*args):
+        seen["ctx"] = orig(*args)
+        return seen["ctx"]
+    with one_device_op_per_dispatch(), \
+            mock.patch.object(spec, "peaks", lambda kind: peaks), \
+            mock.patch.object(serve, "trace_context", keep):
+        line = serve.run_cell(cell, SEED, 1.0, True, jax.devices(), 0.0,
+                              prep=prep, say=lambda m: None)
+    assert line["correct"], line["checks"]
+    metrics = line["metrics"]
+    assert set(metrics) == {m["name"] for m in cell["per_layer"]}
+    assert set(ENGINE_METRICS) <= set(metrics)
+    units = {m["name"]: m["unit"] for m in cell["per_layer"]}
+    for name in ENGINE_METRICS:
+        assert metrics[name] == dict(seen["ctx"]["engine"][name],
+                                     unit=units[name])
+    sched = serve.schedule(cell["mix"], 1.0, prep["traffic_seed"])
+    routes = [prep["engine"].plan_route(int(n)) for n in sched["rows"]]
+    batch = sum(b for r in routes for _, _, b in r)
+    assert metrics["serve_pad_efficiency"]["value"] == pytest.approx(
+        100.0 * sched["rows"].sum() / batch)
+
+
+def test_a_fit_trace_context_carries_the_programs_spans():
+    """``run.trace_context`` reduces the program's spans over the fit's
+    window, from the first chunk's dispatch to the window's end."""
+    import jax
+    with tempfile.TemporaryDirectory() as trace_dir:
+        with jax.profiler.trace(trace_dir):
+            with run.span("bench.window_fit"):
+                with run.span("bench.chunk_dispatch"):
+                    with jax.profiler.TraceAnnotation("dpmm.fit.chunk",
+                                                      iters=5):
+                        pass
+        with mock.patch.object(tracereduce, "load_events",
+                               _with_a_device_op(tracereduce.load_events)):
+            ctx = run.trace_context(
+                {"config": {"family": "gaussian",
+                            "data": {"n": 100, "d": 2}}, "chips": 1},
+                trace_dir, {"start": 0.0, "compile_spans": []}, 5, 3.0,
+                "TPU v5 lite")
+    assert ctx["program"]["dpmm.fit.chunk"]["count"] == 1
+    assert ctx["program"]["dpmm.fit.chunk"]["args"] == {"iters": 5}
+    assert ctx["work"] == spec.load_module("counts", "gaussian").work(
+        100, 2, 3.0)
+
+
+def _with_a_device_op(load):
+    """``load`` with one operation on a stand-in device plane, covering
+    the benchmark's first host span."""
+    def loaded(trace_dir):
+        events = load(trace_dir)
+        _, s, d = events["host"][0]
+        return dict(events, devices={"/device:TPU:0": [["fusion", s, d]]})
+    return loaded
